@@ -72,8 +72,8 @@ SEED = 11
 
 
 def build_plane(n_targets: int = N_TARGETS, *, shards: int = N_TARGETS,
-                enable_cache: bool = False):
-    dev = BlockDevice(num_blocks=1 << 16)
+                enable_cache: bool = False, num_blocks: int = 1 << 16):
+    dev = BlockDevice(num_blocks=num_blocks)
     fs = OffloadFS(dev, node="init0", shards=shards)
     fabric = FaultyFabric(seed=SEED)
     engines = []

@@ -29,8 +29,9 @@ def main():
     t0 = time.time()
     out = generate(model, params, prompts, steps=steps, max_len=S + steps)
     dt = time.time() - t0
+    dev = jax.devices()[0]
     print(f"generated {B}x{steps} tokens in {dt:.2f}s "
-          f"({B*steps/dt:.1f} tok/s on 1 CPU core)")
+          f"({B*steps/dt:.1f} tok/s on {dev.platform} {dev.device_kind})")
     print("sample:", out[0].tolist())
     # decode is deterministic: same prompt → same continuation
     out2 = generate(model, params, prompts, steps=steps, max_len=S + steps)

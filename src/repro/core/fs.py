@@ -301,7 +301,9 @@ class OffloadFS:
         import zlib
 
         with self._lock:
-            blob = _pkl.dumps(
+            # compressed: one inode per KV-cache chunk file makes the table
+            # long and repetitive, and the area holds only 192 KiB
+            blob = zlib.compress(_pkl.dumps(
                 {
                     "names": dict(self._names),
                     "inodes": {
@@ -314,7 +316,7 @@ class OffloadFS:
                     "clock": self._clock,
                     "shards": self.shards,
                 }
-            )
+            ))
             hdr = len(blob).to_bytes(8, "little") + zlib.crc32(blob).to_bytes(4, "little")
             buf = hdr + blob
             cap = SB_META_BLOCKS * BLOCK_SIZE
@@ -352,7 +354,7 @@ class OffloadFS:
             # torn superblock: fresh mount (last commit wins upstream)
             fs._replay_lease_journal()
             return fs
-        meta = _pkl.loads(blob)
+        meta = _pkl.loads(zlib.decompress(blob))
         fs._names = dict(meta["names"])
         fs._clock = meta["clock"]
         persisted = meta.get("shards", 1)  # pre-striping superblocks: flat

@@ -462,9 +462,8 @@ def merge_row_streams(streams: List[List[tuple]]) -> List[tuple]:
     while len(arrs) > 1:
         nxt = []
         for i in range(0, len(arrs) - 1, 2):
-            mk, mv = ops.merge_sorted(arrs[i][0], arrs[i][1],
-                                      arrs[i + 1][0], arrs[i + 1][1])
-            nxt.append((np.asarray(mk), np.asarray(mv)))
+            nxt.append(ops.merge_sorted(arrs[i][0], arrs[i][1],
+                                        arrs[i + 1][0], arrs[i + 1][1]))
         if len(arrs) % 2:
             nxt.append(arrs[-1])
         arrs = nxt

@@ -1,8 +1,9 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On this CPU container the kernels run with ``interpret=True`` (Pallas
-executes the kernel body in Python) — the TPU target uses the same
-BlockSpecs natively. ``INTERPRET`` flips automatically.
+The kernels compile for the TPU natively. On a CPU backend (tests,
+benchmarks) they run with ``interpret=True`` — Pallas executes the kernel
+body through XLA:CPU with the same BlockSpecs. The mode is decided when a
+wrapper is traced, from the backend in use then; a TPU never interprets.
 """
 from __future__ import annotations
 
@@ -16,7 +17,10 @@ from repro.kernels import flash_attention as _fa
 from repro.kernels import kvmerge as _kv
 from repro.kernels import preprocess as _pp
 
-INTERPRET = jax.default_backend() != "tpu"
+
+def interpret_mode() -> bool:
+    """True only on the CPU backend, where Pallas has no native target."""
+    return jax.default_backend() == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "softcap", "block_q", "block_kv"))
@@ -31,7 +35,7 @@ def flash_attention(q, k, v, *, causal=True, softcap=0.0, block_q=256, block_kv=
     o = _fa.flash_attention(
         qf, kf, vf, causal=causal, softcap=softcap,
         block_q=min(block_q, Sq), block_kv=min(block_kv, Sk),
-        interpret=INTERPRET,
+        interpret=interpret_mode(),
     )
     return o.reshape(B, KV, G, Sq, D).transpose(0, 3, 1, 2, 4)
 
@@ -39,34 +43,47 @@ def flash_attention(q, k, v, *, causal=True, softcap=0.0, block_q=256, block_kv=
 # one bitonic_merge invocation holds both runs in VMEM (kvmerge docstring:
 # n ≤ 64 Ki keys per side); longer runs tile through the kernel below
 MERGE_MAX_RUN = 1 << 16
+# shortest padded run: 2n = 1024 keys fill one (8, 128) tile, the smallest
+# block the chip lays out; it also caps the distinct kernel shapes at eight
+MERGE_MIN_RUN = 512
 
 
 def _key_sentinel(dtype):
     """Largest representable key — the padding value for short runs. Real
     keys must stay strictly below it."""
-    dtype = jnp.dtype(dtype)
-    if jnp.issubdtype(dtype, jnp.floating):
-        return jnp.array(jnp.inf, dtype)
-    return jnp.array(jnp.iinfo(dtype).max, dtype)
+    dtype = np.dtype(dtype)
+    if np.issubdtype(dtype, np.floating):
+        return np.array(np.inf, dtype)
+    return np.array(np.iinfo(dtype).max, dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("n",))
-def _merge_padded(a_keys, a_vals, b_keys, b_vals, *, n):
-    """Pad both runs to length n (power of two) with key sentinels and run
-    the kernel once. Padding happens OUTSIDE the kernel (host/jnp level):
-    the kernel geometry stays fixed power-of-two as the VPU wants it."""
-    sent = _key_sentinel(a_keys.dtype)
+@jax.jit
+def _merge_padded(a_keys, a_vals, b_keys, b_vals):
+    """Merge two runs of equal power-of-two length n ≥ ``MERGE_MIN_RUN``
+    (already sentinel-padded) with one kernel call. b is reversed here so
+    the kernel sees the bitonic concat(a, reverse(b)), lane-dense."""
+    shape = (2 * a_keys.shape[0] // _kv.LANES, _kv.LANES)
+    keys = jnp.concatenate([a_keys, b_keys[::-1]]).reshape(shape)
+    vals = jnp.concatenate([a_vals, b_vals[::-1]]).reshape(shape)
+    ok, ov = _kv.bitonic_merge(keys, vals, interpret=interpret_mode())
+    return ok.reshape(-1), ov.reshape(-1)
+
+
+def _merge_run_pair(ak, av, bk, bv):
+    """Host-pad two runs (each ≤ ``MERGE_MAX_RUN``) to the kernel's
+    power-of-two geometry, merge on the device, slice back to host. Padding
+    on the host keeps one compiled shape per power of two."""
+    total = ak.shape[0] + bk.shape[0]
+    n = max(MERGE_MIN_RUN, 1 << (max(ak.shape[0], bk.shape[0]) - 1).bit_length())
+    sent = _key_sentinel(ak.dtype)
 
     def pad(x, fill):
-        return jnp.concatenate(
-            [x, jnp.full((n - x.shape[0],), fill, x.dtype)]
-        )
+        out = np.full((n,), fill, x.dtype)
+        out[: x.shape[0]] = x
+        return out
 
-    return _kv.bitonic_merge(
-        pad(a_keys, sent), pad(a_vals, jnp.array(0, a_vals.dtype)),
-        pad(b_keys, sent), pad(b_vals, jnp.array(0, b_vals.dtype)),
-        interpret=INTERPRET,
-    )
+    ok, ov = _merge_padded(pad(ak, sent), pad(av, 0), pad(bk, sent), pad(bv, 0))
+    return np.asarray(ok)[:total], np.asarray(ov)[:total]
 
 
 def _merge_diag(ak, bk, d):
@@ -89,40 +106,31 @@ def merge_sorted(a_keys, a_vals, b_keys, b_vals):
     (``MERGE_MAX_RUN`` per side) are tiled through the kernel along the
     merge path (one host-side binary search per tile boundary). Keys must
     be strictly below the dtype's maximum (the padding sentinel). Returns
-    (keys, vals) of length ``len(a) + len(b)``."""
-    a_keys, a_vals = jnp.asarray(a_keys), jnp.asarray(a_vals)
-    b_keys, b_vals = jnp.asarray(b_keys), jnp.asarray(b_vals)
-    na, nb = a_keys.shape[0], b_keys.shape[0]
+    host (keys, vals) arrays of length ``len(a) + len(b)``."""
+    ak, av = np.asarray(a_keys), np.asarray(a_vals)
+    bk, bv = np.asarray(b_keys), np.asarray(b_vals)
+    na, nb = ak.shape[0], bk.shape[0]
     total = na + nb
     if na == 0 or nb == 0:
-        src_k, src_v = (b_keys, b_vals) if na == 0 else (a_keys, a_vals)
-        return src_k, src_v
-    n = 1 << max(0, (max(na, nb) - 1).bit_length())
-    if n <= MERGE_MAX_RUN:
-        ok, ov = _merge_padded(a_keys, a_vals, b_keys, b_vals, n=n)
-        return ok[:total], ov[:total]
+        return (bk, bv) if na == 0 else (ak, av)
+    if max(na, nb) <= MERGE_MAX_RUN:
+        return _merge_run_pair(ak, av, bk, bv)
     # tiled: output tile t covers merged positions [t*T, (t+1)*T); the
     # merge-path diagonal pins which slice of each run feeds the tile
-    ak = np.asarray(a_keys)
-    bk = np.asarray(b_keys)
     T = MERGE_MAX_RUN
     out_k, out_v = [], []
     for d0 in range(0, total, T):
         d1 = min(d0 + T, total)
         i0, i1 = _merge_diag(ak, bk, d0), _merge_diag(ak, bk, d1)
         j0, j1 = d0 - i0, d1 - i1
-        ta_k, ta_v = a_keys[i0:i1], a_vals[i0:i1]
-        tb_k, tb_v = b_keys[j0:j1], b_vals[j0:j1]
         if i0 == i1 or j0 == j1:
-            k = jnp.concatenate([ta_k, tb_k])
-            v = jnp.concatenate([ta_v, tb_v])
+            k = np.concatenate([ak[i0:i1], bk[j0:j1]])
+            v = np.concatenate([av[i0:i1], bv[j0:j1]])
         else:
-            tn = 1 << max(0, (max(i1 - i0, j1 - j0) - 1).bit_length())
-            k, v = _merge_padded(ta_k, ta_v, tb_k, tb_v, n=tn)
-            k, v = k[: d1 - d0], v[: d1 - d0]
+            k, v = _merge_run_pair(ak[i0:i1], av[i0:i1], bk[j0:j1], bv[j0:j1])
         out_k.append(k)
         out_v.append(v)
-    return jnp.concatenate(out_k), jnp.concatenate(out_v)
+    return np.concatenate(out_k), np.concatenate(out_v)
 
 
 def preprocess_image(img_chw, *, out_size=224, flip=False, mean=None, std=None):
@@ -134,6 +142,7 @@ def preprocess_image(img_chw, *, out_size=224, flip=False, mean=None, std=None):
         mean = np.array([0.485, 0.456, 0.406], np.float32) * 255.0
     if std is None:
         std = np.array([0.229, 0.224, 0.225], np.float32) * 255.0
-    mean = jnp.asarray(mean, jnp.float32).reshape(C, 1)
-    std = jnp.asarray(std, jnp.float32).reshape(C, 1)
-    return _pp.preprocess_plane(img_chw, ry, rxt, mean, std, interpret=INTERPRET)
+    mean = jnp.asarray(mean, jnp.float32).reshape(C, 1, 1)
+    std = jnp.asarray(std, jnp.float32).reshape(C, 1, 1)
+    return _pp.preprocess_plane(img_chw, ry, rxt, mean, std,
+                                interpret=interpret_mode())

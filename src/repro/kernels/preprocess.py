@@ -1,6 +1,6 @@
 """Pallas TPU fused image preprocessing: bilinear resize + horizontal flip
 + per-channel normalization in ONE HBM round trip (OffloadPrep's compute,
-TPU-adapted per DESIGN.md §3).
+TPU-adapted).
 
 Hardware adaptation: bilinear resize is a gather on GPUs/CPUs; gathers are
 weak on TPU. Reformulated as two *banded matmuls* on the MXU:
@@ -48,14 +48,16 @@ def _prep_kernel(img_ref, ry_ref, rxt_ref, mean_ref, std_ref, o_ref):
     t = jax.lax.dot_general(
         t, rxt, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
-    mean = mean_ref[0, 0]
-    std = std_ref[0, 0]
+    mean = mean_ref[0]  # (1, 1)
+    std = std_ref[0]
     o_ref[0] = ((t - mean) / std).astype(o_ref.dtype)
 
 
 def preprocess_plane(img, ry, rxt, mean, std, *, interpret=False):
-    """img (C,H,W) f32; ry (oh,H); rxt (W,ow); mean/std (C,1) f32 →
-    (C,oh,ow) f32 normalized (resize+flip baked into ry/rxt)."""
+    """img (C,H,W) f32; ry (oh,H); rxt (W,ow); mean/std (C,1,1) f32 →
+    (C,oh,ow) f32 normalized (resize+flip baked into ry/rxt). mean/std
+    carry two unit dims so each channel's (1,1,1) block spans the array's
+    last two dims, as the chip's block rule requires."""
     C, H, W = img.shape
     oh = ry.shape[0]
     ow = rxt.shape[1]
@@ -66,8 +68,8 @@ def preprocess_plane(img, ry, rxt, mean, std, *, interpret=False):
             pl.BlockSpec((1, H, W), lambda c: (c, 0, 0)),
             pl.BlockSpec((oh, H), lambda c: (0, 0)),
             pl.BlockSpec((W, ow), lambda c: (0, 0)),
-            pl.BlockSpec((1, 1), lambda c: (c, 0)),
-            pl.BlockSpec((1, 1), lambda c: (c, 0)),
+            pl.BlockSpec((1, 1, 1), lambda c: (c, 0, 0)),
+            pl.BlockSpec((1, 1, 1), lambda c: (c, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, oh, ow), lambda c: (c, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((C, oh, ow), jnp.float32),

@@ -32,7 +32,8 @@ otherwise, and directly against the device (under scoped
 Fetched chunks complete out of order (streamed futures across targets);
 the assembly order is recovered by merging the completion log's ascending
 chunk-index runs with the Pallas bitonic-merge kernel
-(``ops.merge_sorted`` — the same kernel that merges SSTable runs).
+(``ops.merge_sorted`` — the same kernel that merges the pushdown scan's
+per-target row streams; compaction merges SSTable runs on the host).
 """
 from __future__ import annotations
 
@@ -342,12 +343,25 @@ class KvCacheStore:
         return _unpack_cache(blob)
 
     # ------------------------------------------------------------- planes
+    # every chunk in flight holds a lease, and a write lease is a journal
+    # record: the superblock's journal area holds ~2.8 Ki live grants, so a
+    # large cache (a full-width prefill is thousands of chunks) moves in
+    # waves of at most this many chunks
+    WAVE_CHUNKS = 1024
+
     def _run_specs(self, specs: List[dict], *, write: bool) -> List[tuple]:
         """Run chunk specs through whichever plane this store has. Returns
         the COMPLETION-ordered arrival log [(chunk_idx, payload)] — fetch
         assembly reorders it (``_assemble``)."""
         if self.router is None and self.off is None:
             return self._run_local(specs, write=write)
+        arrivals: List[tuple] = []
+        for base in range(0, len(specs), self.WAVE_CHUNKS):
+            arrivals += self._run_wave(specs[base : base + self.WAVE_CHUNKS],
+                                       base)
+        return arrivals
+
+    def _run_wave(self, specs: List[dict], base: int) -> List[tuple]:
         arrivals: List[tuple] = []
         alock = threading.Lock()
 
@@ -372,7 +386,7 @@ class KvCacheStore:
         else:
             futs = self.off.submit(specs, stream=True)
         for i, f in enumerate(futs):
-            f.add_done_callback(on_done(i))
+            f.add_done_callback(on_done(base + i))
         first_exc = None
         for f in futs:
             try:

@@ -2,12 +2,13 @@
 tiny batched serving driver used by examples/serving.py."""
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 
-from repro.models.model import Model
+from repro.models.model import Model, build_model
 
 
 def make_prefill_step(model: Model, max_len: int):
@@ -30,6 +31,16 @@ def make_decode_step(model: Model, sample: str = "greedy"):
     return decode_step
 
 
+@functools.lru_cache(maxsize=16)
+def jitted_steps(cfg, max_len: int):
+    """(prefill, decode) jitted once per (model config, max_len): a
+    server's second request of a shape reuses both executables instead of
+    tracing and compiling fresh closures."""
+    model = build_model(cfg)
+    return (jax.jit(make_prefill_step(model, max_len)),
+            jax.jit(make_decode_step(model)))
+
+
 def generate(model: Model, params, prompt_tokens, *, steps: int, max_len: int,
              batch_extra: Optional[Dict[str, Any]] = None, kv_store=None):
     """Greedy generation loop (host-driven; each step jittable).
@@ -44,8 +55,7 @@ def generate(model: Model, params, prompt_tokens, *, steps: int, max_len: int,
     batch = {"tokens": prompt_tokens}
     if batch_extra:
         batch.update(batch_extra)
-    prefill = jax.jit(make_prefill_step(model, max_len))
-    decode = jax.jit(make_decode_step(model))
+    prefill, decode = jitted_steps(model.cfg, max_len)
     if kv_store is not None and kv_store.contains(prompt_tokens):
         cache = kv_store.fetch(prompt_tokens)
         tok = kv_store.first_token(prompt_tokens)

@@ -181,6 +181,19 @@ def test_offloader_plane_roundtrip():
     wait_no_leases(fs)
 
 
+def test_offloader_plane_cache_larger_than_lease_journal():
+    """More chunks than the lease journal holds live grants (~2.8 Ki) and
+    than the superblock holds uncompressed inodes: the put moves in waves
+    and the compressed inode table still fits."""
+    dev, fs, fabric, engines, off = build_plane()
+    store = KvCacheStore(fs, off=off, chunk_blocks=1)
+    cache = small_cache(1600 * 1024)  # 2 x 6.4 MB of f32 -> ~3200 chunks
+    store.put([6, 0, 0, 0], cache)
+    assert store.stats.put_chunks > 2 * store.WAVE_CHUNKS
+    assert caches_equal(cache, store.fetch([6, 0, 0, 0]))
+    wait_no_leases(fs)
+
+
 def test_router_plane_roundtrip_and_midfetch_kill():
     dev, fs, fabric, engines, off = build_plane()
     router = ClusterRouter(off, max_probe_failures=2)

@@ -14,12 +14,8 @@ from repro.train.step import init_state, make_train_step
 
 
 def fake_mesh(shape=(4, 4), axes=("data", "model")):
-    """AbstractMesh: rule/spec logic without real devices. Handles both
-    AbstractMesh signatures: (shape, axis_names) and ((name, size), ...)."""
-    try:
-        return jax.sharding.AbstractMesh(shape, axes)
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
+    """AbstractMesh: rule/spec logic without real devices."""
+    return jax.sharding.AbstractMesh(shape, axes)
 
 
 def test_rules_divisibility_fallback():
