@@ -33,6 +33,10 @@ import jax
 import numpy as np
 
 HERE = os.path.dirname(os.path.abspath(__file__))
+sys.path[:0] = [os.path.join(HERE, "src"), HERE]
+
+from bench.harness import CompileLog  # noqa: E402
+
 SEED = 0
 BATCH, PROMPT_LEN, DECODE_STEPS = 4, 1024, 16
 N_PROMPTS = 2
@@ -49,30 +53,6 @@ def require(ok: bool, what: str) -> None:
     if not ok:
         raise SmokeFailure(what)
     print(f"  ok: {what}", flush=True)
-
-
-class CompileLog:
-    """Counts XLA compiles (persistent-cache loads included) and cache hits
-    through ``jax.monitoring``."""
-
-    def __init__(self):
-        self.compiles, self.secs, self.hits, self.names = 0, 0.0, 0, []
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, secs, **kw):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.compiles += 1
-            self.secs += secs
-            self.names.append(str(kw.get("fun_name", "?")))
-
-    def _event(self, event, **kw):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-
-    def report(self, label: str) -> None:
-        print(f"compiles [{label}]: {self.compiles} in {self.secs:.3f} s, "
-              f"persistent-cache hits {self.hits}", flush=True)
 
 
 def device_gate():
@@ -207,7 +187,6 @@ def main() -> int:
         jax.config.update("jax_compilation_cache_dir",
                           os.path.join(HERE, ".jax_cache"))
     dev, count = device_gate()
-    sys.path[:0] = [os.path.join(HERE, "src"), HERE]
     from repro.kernels import ops
     from repro.models.config import get_config
 

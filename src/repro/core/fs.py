@@ -23,11 +23,13 @@ from __future__ import annotations
 import itertools
 import struct
 import threading
+import time
 import zlib
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
+from repro import obs
 from repro.core.blockdev import BLOCK_SIZE, BlockDevice
 from repro.core.extents import Extent, ExtentManager
 
@@ -265,6 +267,9 @@ class OffloadFS:
         # reclaim orphaned leases without scanning
         self.lease_journal = LeaseJournal(dev, node=node)
         self._orphans: Dict[int, Lease] = {}  # journaled leases from a crash
+        # thread-seconds in grant_lease + release_lease (the lock wait and
+        # the journal append included), counted while tracing is on
+        self.lease_s = 0.0
         # stripe migration (copy → swap → free) accounting + test failpoint:
         # when set, called with a stage name ("pre_copy" / "post_copy" /
         # "post_swap"); raising MigrationCrash simulates a crash there
@@ -889,6 +894,7 @@ class OffloadFS:
                     write_extents: Sequence[Extent]) -> Lease:
         """Authorize an offloaded task; initiator loses access to the write
         set (and will not mutate the read set) until release."""
+        t0 = time.perf_counter() if obs.ON else None
         with self._lock:
             rb = frozenset(
                 b for e in read_extents for b in range(e.block, e.block + e.nblocks)
@@ -924,9 +930,12 @@ class OffloadFS:
                     # quiesced for the lease's lifetime, so nothing can
                     # re-fill them until release)
                     self.memtier.fence(wb)
+            if t0 is not None:
+                self.lease_s += time.perf_counter() - t0
             return lease
 
     def release_lease(self, lease: Lease) -> None:
+        t0 = time.perf_counter() if obs.ON else None
         with self._lock:
             lease.done = True
             existed = self._leases.pop(lease.task_id, None) is not None
@@ -935,6 +944,13 @@ class OffloadFS:
                     del self._leased_blocks[b]
             if existed and lease.write_blocks:
                 self.lease_journal.append_release(lease.task_id)
+            if t0 is not None:
+                self.lease_s += time.perf_counter() - t0
+
+    @property
+    def lease_records(self) -> int:
+        """Lease-journal records appended (grants and releases)."""
+        return self.lease_journal.appends
 
     # ------------------------------------------------- scoped (CM) leases
     @contextmanager

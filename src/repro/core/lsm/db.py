@@ -29,6 +29,7 @@ from collections import OrderedDict
 from dataclasses import dataclass
 from typing import Dict, Iterable, List, Optional, Tuple
 
+from repro import obs
 from repro.core.blockdev import BLOCK_SIZE
 from repro.core.fs import OffloadFS
 from repro.core import pushdown as P
@@ -345,7 +346,20 @@ class OffloadDB:
         owning overlapping SSTables, submitted with ``reroute=True`` so a
         dead target's share retries elsewhere or lands locally under the
         same read lease."""
-        import heapq
+        with obs.span("lsm.scan"):
+            with obs.span("lsm.scan.plan"):
+                specs, lstream, final = self._plan_pushdown(prog)
+            self.stats["pushdown_scans"] += 1
+            with obs.span("lsm.scan.wire"):
+                results = self.off.submit(specs) if specs else []
+            with obs.span("lsm.scan.merge"):
+                return self._merge_pushdown(prog, limit, results, lstream,
+                                            final)
+
+    def _plan_pushdown(self, prog: dict):
+        """The sub-scan specs, one per stripe owning overlapping SSTables,
+        the initiator-resident row stream, and whether a single sub-scan
+        may aggregate the whole range itself."""
         lo, hi = prog["lo"], prog.get("hi")
         local, tables = self._ranked_sources(lo, hi)
         lstream = self._local_wire_rows(prog, local)
@@ -376,8 +390,15 @@ class OffloadDB:
             "mtime": g["mtime"],
             "reroute": True,
         } for _, g in sorted(groups.items())]
-        self.stats["pushdown_scans"] += 1
-        results = self.off.submit(specs) if specs else []
+        return specs, lstream, final
+
+    def _merge_pushdown(self, prog: dict, limit: Optional[int], results,
+                        lstream: List[tuple], final: bool):
+        """Merge the sub-scans' rows (or aggregate states) with the
+        initiator's stream into the scan's answer."""
+        import heapq
+
+        agg = prog.get("aggregate")
         streams = [lstream] if lstream else []
         agg_states = []
         for res, _where in results:

@@ -45,6 +45,8 @@ import pickle
 import struct
 from typing import Any, Iterable, List, Optional, Sequence, Tuple
 
+from repro import obs
+
 # NOTE: repro.core.lsm imports are deferred into the functions that need
 # them — repro.core.lsm.__init__ imports db, and db imports this module.
 
@@ -398,33 +400,35 @@ def stub_pushdown_scan(io, tables: List[dict], prog: dict, *,
     lo, hi = prog["lo"], prog.get("hi")
     agg = prog.get("aggregate")
     key_only = prog.get("project") == "key"
-    sources = []
-    for t in tables:
-        r = SSTableReader(_read_runs(io, t["runs"], t["size"]))
-        sources.append((int(t["rank"]), r.range_items(lo, hi)))
-    matched: List[tuple] = []
-    markers: List[Tuple[bytes, int]] = []
-    state = agg_init(agg) if agg else None
-    scanned = 0
-    for k, rank, v in _merge_ranked(sources):
-        scanned += 1
-        if v == TOMBSTONE or not eval_filter(prog, k, v):
-            if not final:
-                markers.append((k, rank))
-            continue
+    with obs.span("pushdown.read"):
+        sources = []
+        for t in tables:
+            r = SSTableReader(_read_runs(io, t["runs"], t["size"]))
+            sources.append((int(t["rank"]), r.range_items(lo, hi)))
+    with obs.span("pushdown.eval"):
+        matched: List[tuple] = []
+        markers: List[Tuple[bytes, int]] = []
+        state = agg_init(agg) if agg else None
+        scanned = 0
+        for k, rank, v in _merge_ranked(sources):
+            scanned += 1
+            if v == TOMBSTONE or not eval_filter(prog, k, v):
+                if not final:
+                    markers.append((k, rank))
+                continue
+            if final and agg:
+                state = agg_add(agg, state, k, len(v))
+            elif agg:
+                matched.append((k, rank, len(v)))
+            else:
+                matched.append((k, rank, b"" if key_only else v))
+        if eng is not None:
+            eng.pushdown_scans += 1
+            eng.pushdown_rows_in += scanned
+            eng.pushdown_rows_out += len(matched)
         if final and agg:
-            state = agg_add(agg, state, k, len(v))
-        elif agg:
-            matched.append((k, rank, len(v)))
-        else:
-            matched.append((k, rank, b"" if key_only else v))
-    if eng is not None:
-        eng.pushdown_scans += 1
-        eng.pushdown_rows_in += scanned
-        eng.pushdown_rows_out += len(matched)
-    if final and agg:
-        return ("agg", state, scanned)
-    return ("rows", matched, pack_markers(markers), scanned)
+            return ("agg", state, scanned)
+        return ("rows", matched, pack_markers(markers), scanned)
 
 
 # -------------------------------------------------- initiator-side merge

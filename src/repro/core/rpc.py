@@ -24,6 +24,8 @@ import time
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
+from repro import obs
+
 
 @dataclass
 class RpcRecord:
@@ -105,6 +107,9 @@ class RpcFabric:
         self._n_workers = workers
         self._workers: List[threading.Thread] = []
         self._jobs: "queue.Queue" = queue.Queue()
+        # thread-seconds in pickle.dumps/loads (the wire's copies), counted
+        # while tracing is on (repro.obs)
+        self.pickle_s = 0.0
 
     # -------------------------------------------------------- registration
     def register(self, node: str, method: str, fn: Callable) -> None:
@@ -159,26 +164,44 @@ class RpcFabric:
                     )
             self._flushed.notify_all()
 
+    def _dumps(self, obj) -> bytes:
+        return self._pickle(pickle.dumps, obj)
+
+    def _loads(self, blob: bytes) -> Any:
+        return self._pickle(pickle.loads, blob)
+
+    def _pickle(self, fn: Callable, arg: Any) -> Any:
+        """``fn(arg)``; its thread-seconds go to ``pickle_s`` while tracing
+        is on."""
+        if not obs.ON:
+            return fn(arg)
+        t0 = time.perf_counter()
+        out = fn(arg)
+        dt = time.perf_counter() - t0
+        with self._lock:
+            self.pickle_s += dt
+        return out
+
     # ----------------------------------------------------------- sync path
     def call(self, src: str, dst: str, method: str, *args, **kwargs) -> Any:
-        req = pickle.dumps((args, kwargs))  # may raise — before seq alloc
+        req = self._dumps((args, kwargs))  # may raise — before seq alloc
         seq = self._alloc_seq()
         try:
             fn = self._handler(dst, method)
         except RpcError:
             self._land(seq, None)  # never left the initiator
             raise
-        a, kw = pickle.loads(req)  # honest copy across the "wire"
+        a, kw = self._loads(req)  # honest copy across the "wire"
         try:
             result = fn(*a, **kw)
-            resp = pickle.dumps(result)
+            resp = self._dumps(result)
         except Exception as e:
             # an error response crosses the wire too
-            err = pickle.dumps(repr(e))
+            err = self._dumps(repr(e))
             self._land(seq, RpcRecord(src, dst, method, len(req), len(err)))
             raise
         self._land(seq, RpcRecord(src, dst, method, len(req), len(resp)))
-        return pickle.loads(resp)
+        return self._loads(resp)
 
     # ---------------------------------------------------------- async path
     def _ensure_workers(self) -> None:
@@ -209,7 +232,7 @@ class RpcFabric:
         """Submit without blocking; the seq (and hence the replay-record
         position) is fixed NOW, whatever order workers finish in."""
         self._ensure_workers()
-        req = pickle.dumps((args, kwargs))  # may raise — before seq alloc
+        req = self._dumps((args, kwargs))  # may raise — before seq alloc
         seq = self._alloc_seq()
         fut = RpcFuture()
 
@@ -221,18 +244,18 @@ class RpcFabric:
                 fut.set_exception(e)
                 return
             try:
-                a, kw = pickle.loads(req)
+                a, kw = self._loads(req)
                 result = fn(*a, **kw)
-                resp = pickle.dumps(result)
+                resp = self._dumps(result)
             except BaseException as e:  # noqa: BLE001 - propagated via future
-                err = pickle.dumps(repr(e))
+                err = self._dumps(repr(e))
                 self._land(seq, RpcRecord(src, dst, method, len(req), len(err)))
                 fut.set_exception(e)
                 return
             self._land(seq, RpcRecord(src, dst, method, len(req), len(resp)))
-            fut.set_result(pickle.loads(resp))
+            fut.set_result(self._loads(resp))
 
-        self._jobs.put(run)
+        self._jobs.put(obs.carry(run))
         return fut
 
     # ---------------------------------------------------------- batch path
@@ -249,36 +272,37 @@ class RpcFabric:
 
     def _execute_batch(self, seq: int, src: str, dst: str,
                        calls: Sequence[Tuple[str, tuple, dict]]) -> List[Any]:
-        try:
-            reqs = [pickle.dumps((args, kwargs)) for _, args, kwargs in calls]
-        except Exception:
-            self._land(seq, None)  # unpicklable request: nothing hit the wire
-            raise
-        req_bytes = sum(len(r) for r in reqs)
-        methods = [m for m, _, _ in calls]
-        try:
-            fns = [self._handler(dst, m) for m in methods]
-        except RpcError:
-            self._land(seq, None)
-            raise
-        label = f"batch:{methods[0]}" if len(set(methods)) == 1 else "batch:mixed"
-        results: List[Any] = []
-        resp_bytes = 0
-        try:
-            for fn, wire in zip(fns, reqs):
-                a, kw = pickle.loads(wire)
-                r = fn(*a, **kw)
-                blob = pickle.dumps(r)
-                resp_bytes += len(blob)
-                results.append(pickle.loads(blob))
-        except Exception as e:
-            resp_bytes += len(pickle.dumps(repr(e)))
+        with obs.span("fabric.batch"):
+            try:
+                reqs = [self._dumps((args, kwargs)) for _, args, kwargs in calls]
+            except Exception:
+                self._land(seq, None)  # unpicklable request: nothing hit the wire
+                raise
+            req_bytes = sum(len(r) for r in reqs)
+            methods = [m for m, _, _ in calls]
+            try:
+                fns = [self._handler(dst, m) for m in methods]
+            except RpcError:
+                self._land(seq, None)
+                raise
+            label = f"batch:{methods[0]}" if len(set(methods)) == 1 else "batch:mixed"
+            results: List[Any] = []
+            resp_bytes = 0
+            try:
+                for fn, wire in zip(fns, reqs):
+                    a, kw = self._loads(wire)
+                    r = fn(*a, **kw)
+                    blob = self._dumps(r)
+                    resp_bytes += len(blob)
+                    results.append(self._loads(blob))
+            except Exception as e:
+                resp_bytes += len(self._dumps(repr(e)))
+                self._land(seq, RpcRecord(src, dst, label, req_bytes, resp_bytes,
+                                          n_calls=len(calls)))
+                raise
             self._land(seq, RpcRecord(src, dst, label, req_bytes, resp_bytes,
                                       n_calls=len(calls)))
-            raise
-        self._land(seq, RpcRecord(src, dst, label, req_bytes, resp_bytes,
-                                  n_calls=len(calls)))
-        return results
+            return results
 
     def call_batch_async(self, src: str, dst: str,
                          calls: Sequence[Tuple[str, tuple, dict]]) -> RpcFuture:
@@ -297,7 +321,7 @@ class RpcFabric:
             except BaseException as e:  # noqa: BLE001
                 fut.set_exception(e)
 
-        self._jobs.put(run)
+        self._jobs.put(obs.carry(run))
         return fut
 
     # ------------------------------------------------------------- stats

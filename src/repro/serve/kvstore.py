@@ -47,6 +47,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core.blockdev import BLOCK_SIZE
 from repro.core.fs import OffloadFS
 
@@ -257,90 +258,99 @@ class KvCacheStore:
         ``{"key", "shard", "deduped", "bytes"}``. A same-stripe replica
         already present answers the put with zero I/O (the dedupe hit the
         placement policy is supposed to manufacture)."""
-        t = _norm_tokens(tokens)
-        key = self._key(t)
-        with self._lock:
-            self.stats.puts += 1
-            shard = self._place(t)
-            entry = self._entries.get(key)
-            if entry is not None and shard in entry.replicas:
-                entry.last_used = self._clock()  # a dedupe hit is a use
-                self.stats.dedupe_hits += 1
-                return {"key": key, "shard": shard, "deduped": True,
-                        "bytes": 0}
-        blob = _pack_cache(cache)
-        base = f"{self.root}/{key}/s{shard}"
-        chunks = [blob[i : i + self.chunk_bytes]
-                  for i in range(0, len(blob), self.chunk_bytes)] or [b""]
-        specs = []
-        for k, chunk in enumerate(chunks):
-            path = f"{base}/c{k}"
-            self.fs.create(path, shard=shard)
-            self.fs.fallocate(path, len(chunk))
-            ino = self.fs.stat(path)
-            runs = [(e.block, e.nblocks) for e in ino.extents]
-            specs.append({
-                "task": "kv_put", "args": (runs, chunk),
-                "write_extents": ino.extents,
-                "mtime": self.fs.stat(path).mtime,
-            })
-        self._failpoint = failpoint
-        try:
-            self._run_specs(specs, write=True)
-        finally:
-            self._failpoint = None
-        with self._lock:
-            entry = self._entries.get(key)
-            if entry is None:
-                entry = KvEntry(key, t, len(blob), len(chunks))
-                self._entries[key] = entry
-            entry.replicas[shard] = base
-            entry.last_used = self._clock()
-            if first_token is not None:
-                entry.first = np.asarray(first_token)
-            self.stats.put_chunks += len(chunks)
-            self.stats.put_bytes += len(blob)
-            # capacity back-pressure: evict colder entries before the
-            # catalog commit so one persist covers insert + eviction (the
-            # fresh entry itself is protected from its own sweep)
-            self._evict_locked(protect=key)
-            self._persist_catalog()
-            # commit point: a standby that takes the volume over must see
-            # the chunk inodes + catalog of every completed put
-            self.fs.flush_metadata()
-        return {"key": key, "shard": shard, "deduped": False,
-                "bytes": len(blob)}
+        with obs.span("kvstore.put"):
+            t = _norm_tokens(tokens)
+            key = self._key(t)
+            with self._lock:
+                self.stats.puts += 1
+                shard = self._place(t)
+                entry = self._entries.get(key)
+                if entry is not None and shard in entry.replicas:
+                    entry.last_used = self._clock()  # a dedupe hit is a use
+                    self.stats.dedupe_hits += 1
+                    return {"key": key, "shard": shard, "deduped": True,
+                            "bytes": 0}
+            with obs.span("kvstore.put.pack"):
+                blob = _pack_cache(cache)
+            with obs.span("kvstore.put.layout"):
+                base = f"{self.root}/{key}/s{shard}"
+                chunks = [blob[i : i + self.chunk_bytes] for i in
+                          range(0, len(blob), self.chunk_bytes)] or [b""]
+                specs = []
+                for k, chunk in enumerate(chunks):
+                    path = f"{base}/c{k}"
+                    self.fs.create(path, shard=shard)
+                    self.fs.fallocate(path, len(chunk))
+                    ino = self.fs.stat(path)
+                    runs = [(e.block, e.nblocks) for e in ino.extents]
+                    specs.append({
+                        "task": "kv_put", "args": (runs, chunk),
+                        "write_extents": ino.extents,
+                        "mtime": self.fs.stat(path).mtime,
+                    })
+            self._failpoint = failpoint
+            try:
+                with obs.span("kvstore.put.wire"):
+                    self._run_specs(specs, write=True)
+            finally:
+                self._failpoint = None
+            with obs.span("kvstore.put.commit"), self._lock:
+                entry = self._entries.get(key)
+                if entry is None:
+                    entry = KvEntry(key, t, len(blob), len(chunks))
+                    self._entries[key] = entry
+                entry.replicas[shard] = base
+                entry.last_used = self._clock()
+                if first_token is not None:
+                    entry.first = np.asarray(first_token)
+                self.stats.put_chunks += len(chunks)
+                self.stats.put_bytes += len(blob)
+                # capacity back-pressure: evict colder entries before the
+                # catalog commit so one persist covers insert + eviction
+                # (the fresh entry itself is protected from its own sweep)
+                self._evict_locked(protect=key)
+                self._persist_catalog()
+                # commit point: a standby that takes the volume over must
+                # see the chunk inodes + catalog of every completed put
+                self.fs.flush_metadata()
+            return {"key": key, "shard": shard, "deduped": False,
+                    "bytes": len(blob)}
 
     # -------------------------------------------------------------- fetch
     def fetch(self, tokens):
         """Decode-side attach: stream the stored cache for ``tokens`` back
         (exact prompt match) and rebuild the pytree. Returns None when the
         prompt was never stored (the caller recomputes prefill)."""
-        t = _norm_tokens(tokens)
-        with self._lock:
-            entry = self._entries.get(self._key(t))
-            if entry is not None:
-                entry.last_used = self._clock()  # LRU touch
-        if entry is None or entry.tokens != t:
-            return None
-        shard = min(entry.replicas)
-        base = entry.replicas[shard]
-        specs = []
-        for k in range(entry.nchunks):
-            path = f"{base}/c{k}"
-            ino = self.fs.stat(path)
-            runs = [(e.block, e.nblocks) for e in ino.extents]
-            specs.append({
-                "task": "kv_get", "args": (runs, ino.size),
-                "read_extents": ino.extents, "mtime": ino.mtime,
-            })
-        arrivals = self._run_specs(specs, write=False)
-        blob = self._assemble(arrivals)[: entry.size]
-        with self._lock:
-            self.stats.fetches += 1
-            self.stats.fetch_bytes += len(blob)
-            self.stats.fetch_chunks += len(specs)
-        return _unpack_cache(blob)
+        with obs.span("kvstore.fetch"):
+            t = _norm_tokens(tokens)
+            with self._lock:
+                entry = self._entries.get(self._key(t))
+                if entry is not None:
+                    entry.last_used = self._clock()  # LRU touch
+            if entry is None or entry.tokens != t:
+                return None
+            with obs.span("kvstore.fetch.plan"):
+                shard = min(entry.replicas)
+                base = entry.replicas[shard]
+                specs = []
+                for k in range(entry.nchunks):
+                    path = f"{base}/c{k}"
+                    ino = self.fs.stat(path)
+                    runs = [(e.block, e.nblocks) for e in ino.extents]
+                    specs.append({
+                        "task": "kv_get", "args": (runs, ino.size),
+                        "read_extents": ino.extents, "mtime": ino.mtime,
+                    })
+            with obs.span("kvstore.fetch.wire"):
+                arrivals = self._run_specs(specs, write=False)
+            with obs.span("kvstore.fetch.assemble"):
+                blob = self._assemble(arrivals)[: entry.size]
+            with self._lock:
+                self.stats.fetches += 1
+                self.stats.fetch_bytes += len(blob)
+                self.stats.fetch_chunks += len(specs)
+            with obs.span("kvstore.fetch.unpack"):
+                return _unpack_cache(blob)
 
     # ------------------------------------------------------------- planes
     # every chunk in flight holds a lease, and a write lease is a journal

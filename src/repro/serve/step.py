@@ -8,6 +8,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.models.model import Model, build_model
 
 
@@ -56,6 +57,21 @@ def generate(model: Model, params, prompt_tokens, *, steps: int, max_len: int,
     if batch_extra:
         batch.update(batch_extra)
     prefill, decode = jitted_steps(model.cfg, max_len)
+    with obs.op():
+        with obs.span("serve.attach"):
+            cache, tok = _attach(prefill, params, batch, prompt_tokens,
+                                 kv_store)
+        with obs.span("serve.decode"):
+            out = [tok]
+            for _ in range(steps - 1):
+                tok, _, cache = decode(params, cache, tok)
+                out.append(tok)
+            return jnp.concatenate(out, axis=1)
+
+
+def _attach(prefill, params, batch, prompt_tokens, kv_store):
+    """The request's cache and first token: fetched when the store holds
+    the prompt, else prefilled (and, with a store, put and fetched back)."""
     if kv_store is not None and kv_store.contains(prompt_tokens):
         cache = kv_store.fetch(prompt_tokens)
         tok = kv_store.first_token(prompt_tokens)
@@ -70,8 +86,4 @@ def generate(model: Model, params, prompt_tokens, *, steps: int, max_len: int,
                          first_token=jnp.asarray(tok))
             del cache  # decode must run from the offloaded copy
             cache = kv_store.fetch(prompt_tokens)
-    out = [tok]
-    for _ in range(steps - 1):
-        tok, _, cache = decode(params, cache, tok)
-        out.append(tok)
-    return jnp.concatenate(out, axis=1)
+    return cache, tok
