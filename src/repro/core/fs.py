@@ -902,7 +902,9 @@ class OffloadFS:
             wb = frozenset(
                 b for e in write_extents for b in range(e.block, e.block + e.nblocks)
             )
-            overlap = wb & set(self._leased_blocks)
+            # probe the map per write block: the cost is the grant's own
+            # write set, not every block leased so far
+            overlap = [b for b in wb if b in self._leased_blocks]
             if overlap:
                 raise LeaseViolation(f"blocks already leased: {sorted(overlap)[:4]}…")
             tid = next(self._task_counter)

@@ -189,6 +189,109 @@ def test_double_release_is_idempotent():
     fs.release_lease(lease2)
 
 
+def _lease_chunks(fs, n, nblocks=64):
+    """Grant ``n`` write leases of ``nblocks`` each over blocks [0, n*nblocks),
+    as a KV put's wave of chunk grants leaves them outstanding."""
+    from repro.core import Extent
+
+    # reprolint: allow[lease-raw] outstanding leases under test, dropped with the fs
+    return [fs.grant_lease([], [Extent(0, i * nblocks, nblocks)])
+            for i in range(n)]
+
+
+@pytest.mark.parametrize("outstanding, release, reads, writes, refused", [
+    pytest.param(1, False, [], [(32, 64)], [32, 33, 34, 35],
+                 id="write-over-write-refused"),
+    pytest.param(1024, False, [], [(1024 * 64, 64)], None,
+                 id="disjoint-write-under-1024-leases"),
+    pytest.param(1, False, [(0, 64)], [], None,
+                 id="read-over-write-granted"),
+    pytest.param(1, True, [], [(0, 64)], None,
+                 id="regrant-after-release"),
+    pytest.param(1024, False, [], [(1024 * 64, 8), (777, 2)], [777, 778],
+                 id="partial-overlap-refusal-changes-nothing"),
+])
+def test_grant_lease_write_set_conflicts(outstanding, release, reads, writes,
+                                         refused):
+    """A write grant is refused exactly when its write set meets a block
+    under an outstanding write lease; read sets are never checked; a refusal
+    names the lowest overlapping blocks and leaves the maps and the journal
+    as they were."""
+    from repro.core import Extent
+
+    _, fs = make_fs()
+    held = _lease_chunks(fs, outstanding)
+    if release:
+        for lease in held:
+            fs.release_lease(lease)
+    before = (dict(fs._leased_blocks), dict(fs._leases),
+              fs.lease_journal.appends)
+    read_ex = [Extent(0, b, n) for b, n in reads]
+    write_ex = [Extent(0, b, n) for b, n in writes]
+    if refused is not None:
+        with pytest.raises(LeaseViolation) as err:
+            # reprolint: allow[lease-raw] refused grant under test, never held
+            fs.grant_lease(read_ex, write_ex)
+        assert str(refused) in str(err.value)
+        assert (dict(fs._leased_blocks), dict(fs._leases),
+                fs.lease_journal.appends) == before
+        return
+    # reprolint: allow[lease-raw] exercises the raw grant/release lease protocol under test
+    lease = fs.grant_lease(read_ex, write_ex)
+    wb = {b for b, n in writes for b in range(b, b + n)}
+    assert lease.write_blocks == wb
+    assert all(fs._leased_blocks[b] == lease.task_id for b in wb)
+    assert fs.lease_journal.appends == before[2] + (1 if wb else 0)
+    fs.release_lease(lease)
+    assert dict(fs._leased_blocks) == before[0]
+
+
+class _WalkCountingMap(dict):
+    """A block map that counts each walk over its keys or entries."""
+
+    def __init__(self, *args):
+        super().__init__(*args)
+        self.walks = 0
+
+    def _walk(self, view):
+        self.walks += 1
+        return view
+
+    def __iter__(self):
+        return self._walk(super().__iter__())
+
+    def keys(self):
+        return self._walk(super().keys())
+
+    def values(self):
+        return self._walk(super().values())
+
+    def items(self):
+        return self._walk(super().items())
+
+
+def test_grant_lease_never_walks_the_leased_block_map():
+    """With a wave's worth of write leases outstanding, a grant probes the
+    leased-block map per block of its own write set: it never walks the map,
+    so a wave of grants stays linear in its chunks."""
+    from repro.core import Extent
+
+    _, fs = make_fs()
+    _lease_chunks(fs, 1024)
+    fs._leased_blocks = _WalkCountingMap(fs._leased_blocks)
+    # reprolint: allow[lease-raw] exercises the raw grant/release lease protocol under test
+    fresh = fs.grant_lease([], [Extent(0, 1024 * 64, 64)])
+    with pytest.raises(LeaseViolation):
+        # reprolint: allow[lease-raw] refused grant under test, never held
+        fs.grant_lease([], [Extent(0, 100, 4)])
+    # reprolint: allow[lease-raw] exercises the raw grant/release lease protocol under test
+    reader = fs.grant_lease([Extent(0, 0, 64 * 1024)], [])
+    fs.release_lease(reader)
+    fs.release_lease(fresh)
+    assert fs._leased_blocks.walks == 0
+    assert len(fs._leased_blocks) == 1024 * 64
+
+
 def test_stale_mtime_reads_bypass_offload_cache_counted():
     """Coarse mtime coherence: every cached block older than the request's
     mtime is bypassed (and re-read from NVMe), with exact accounting."""
