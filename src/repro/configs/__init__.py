@@ -10,6 +10,7 @@ from repro.configs import (  # noqa: F401
     seamless_m4t_large_v2,
     grok_1_314b,
     granite_moe_3b_a800m,
+    granite_4_0_h_small,
     phi_3_vision_4_2b,
     paper_lm,
 )

@@ -15,6 +15,18 @@ class MoEConfig:
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
     router_z_weight: float = 1e-3
+    # width of a shared SwiGLU expert that every token passes through beside
+    # the routed ones (0: none)
+    shared_d_ff: int = 0
+    # (first, count): the contiguous range of experts this chip holds under
+    # expert parallelism. The router keeps all ``num_experts`` outputs and
+    # its top-k over them; the layer adds only its held experts' part.
+    # None: every expert is held.
+    experts_held: Optional[Tuple[int, int]] = None
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        return self.experts_held or (0, self.num_experts)
 
 
 @dataclass(frozen=True)
@@ -30,6 +42,7 @@ class MambaConfig:
     expand: int = 2
     head_dim: int = 64
     chunk: int = 256
+    conv_bias: bool = False
 
 
 @dataclass(frozen=True)
@@ -66,6 +79,15 @@ class ModelConfig:
     rope_theta: float = 1e4
     rotary_pct: float = 1.0
     attn_logit_softcap: float = 0.0  # grok-style tanh softcap, 0 = off
+    # softmax scale of attention logits; None = 1/sqrt(head_dim)
+    attention_multiplier: Optional[float] = None
+    # µP scalars (Granite): the embedding is multiplied by
+    # ``embedding_multiplier``, every sublayer's output by
+    # ``residual_multiplier`` before its residual add, and the logits are
+    # divided by ``logits_scaling``. None = absent.
+    embedding_multiplier: Optional[float] = None
+    residual_multiplier: Optional[float] = None
+    logits_scaling: Optional[float] = None
     tie_embeddings: bool = False
     # encoder-decoder
     encoder_decoder: bool = False

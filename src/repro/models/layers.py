@@ -119,15 +119,24 @@ def attention_spec(cfg, cross: bool = False) -> dict:
     return spec
 
 
+def attention_scale(cfg) -> float:
+    """Softmax scale of attention logits: the configuration's
+    ``attention_multiplier`` where it sets one, else 1/sqrt(head_dim)."""
+    if cfg.attention_multiplier is not None:
+        return cfg.attention_multiplier
+    return 1.0 / math.sqrt(cfg.head_dim)
+
+
 def _softcap(logits, cap):
     return jnp.tanh(logits / cap) * cap if cap else logits
 
 
-def _einsum_attention(qg, k, v, *, causal, softcap, kv_len=None, q_offset=None):
+def _einsum_attention(qg, k, v, *, scale, causal, softcap, kv_len=None,
+                      q_offset=None):
     """qg (B,Sq,KV,G,D), k/v (B,Sk,KV,D). Returns (B,Sq,KV,G,D)."""
     B, Sq, KV, G, D = qg.shape
     logits = jnp.einsum("bqkgd,bskd->bkgqs", qg, k).astype(jnp.float32)
-    logits = _softcap(logits * (1.0 / math.sqrt(D)), softcap)
+    logits = _softcap(logits * scale, softcap)
     Sk = k.shape[1]
     mask = None
     if causal:
@@ -154,7 +163,8 @@ def _pick_block(n: int, want: int) -> int:
     return n
 
 
-def _flash_attention_jnp(qg, k, v, *, causal, softcap, block_kv=FLASH_BLOCK_KV):
+def _flash_attention_jnp(qg, k, v, *, scale, causal, softcap,
+                         block_kv=FLASH_BLOCK_KV):
     """Online-softmax over KV chunks via lax.scan. Memory O(Sq·block)."""
     B, Sq, KV, G, D = qg.shape
     Sk = k.shape[1]
@@ -162,7 +172,6 @@ def _flash_attention_jnp(qg, k, v, *, causal, softcap, block_kv=FLASH_BLOCK_KV):
     nb = Sk // block_kv
     kb = k.reshape(B, nb, block_kv, KV, D).transpose(1, 0, 2, 3, 4)
     vb = v.reshape(B, nb, block_kv, KV, D).transpose(1, 0, 2, 3, 4)
-    scale = 1.0 / math.sqrt(D)
     qpos = jnp.arange(Sq)
 
     def step(carry, inp):
@@ -194,20 +203,18 @@ def _flash_attention_jnp(qg, k, v, *, causal, softcap, block_kv=FLASH_BLOCK_KV):
     return out.transpose(0, 3, 1, 2, 4).astype(qg.dtype)  # (B,Sq,KV,G,D)
 
 
-def _flash_attention_qchunked(qg, k, v, *, causal, softcap,
+def _flash_attention_qchunked(qg, k, v, *, scale, causal, softcap,
                               block_q=FLASH_BLOCK_Q, block_kv=FLASH_BLOCK_KV):
     """Double-chunked flash twin: outer lax.map over q blocks bounds the
     logits working set to (block_q, block_kv) regardless of Sq."""
     B, Sq, KV, G, D = qg.shape
     if Sq <= block_q:
-        return _flash_attention_jnp(qg, k, v, causal=causal, softcap=softcap,
-                                    block_kv=block_kv)
+        return _flash_attention_jnp(qg, k, v, scale=scale, causal=causal,
+                                    softcap=softcap, block_kv=block_kv)
     block_q = _pick_block(Sq, block_q)
     nq = Sq // block_q
     qb = qg.reshape(B, nq, block_q, KV, G, D).transpose(1, 0, 2, 3, 4, 5)
     Sk = k.shape[1]
-    scale = 1.0 / math.sqrt(D)
-
     block_kv = _pick_block(Sk, block_kv)
 
     def one_q_block(args):
@@ -294,7 +301,8 @@ def apply_attention(
         )
         new_cache = {"k": kc, "v": vc, "len": idx + 1}
         out = _einsum_attention(
-            q, kc, vc, causal=False, softcap=cfg.attn_logit_softcap, kv_len=idx + 1
+            q, kc, vc, scale=attention_scale(cfg), causal=False,
+            softcap=cfg.attn_logit_softcap, kv_len=idx + 1,
         )
     else:
         if mode == "prefill":
@@ -308,12 +316,14 @@ def apply_attention(
             }
         if S > FLASH_THRESHOLD and kv_src is None:
             out = _flash_attention_qchunked(
-                q, k, v, causal=causal, softcap=cfg.attn_logit_softcap
+                q, k, v, scale=attention_scale(cfg), causal=causal,
+                softcap=cfg.attn_logit_softcap,
             )
             scan_flops = attention_scan_flops(B, S, S, cfg.num_heads, cfg.head_dim, causal)
         else:
             out = _einsum_attention(
-                q, k, v, causal=causal, softcap=cfg.attn_logit_softcap
+                q, k, v, scale=attention_scale(cfg), causal=causal,
+                softcap=cfg.attn_logit_softcap,
             )
     out = lac(out, "batch", None, "kv_heads", "q_per_kv", None)
     y = jnp.einsum("bskgd,kgdm->bsm", out, p["wo"].astype(x.dtype))
@@ -335,7 +345,8 @@ def apply_cross_attention(p, cfg, x, enc_out, *, cache=None, mode="train"):
         k = jnp.einsum("bsd,dkh->bskh", enc_out, p["wk"].astype(x.dtype))
         v = jnp.einsum("bsd,dkh->bskh", enc_out, p["wv"].astype(x.dtype))
         new_cache = {"k": k, "v": v} if mode == "prefill" else None
-    out = _einsum_attention(q, k, v, causal=False, softcap=0.0)
+    out = _einsum_attention(q, k, v, scale=attention_scale(cfg), causal=False,
+                            softcap=0.0)
     y = jnp.einsum("bskgd,kgdm->bsm", out, p["wo"].astype(x.dtype))
     return y, new_cache
 

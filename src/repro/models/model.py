@@ -88,6 +88,8 @@ class Model:
         cfg = self.cfg
         e = params["embed"].astype(cfg.compute_dtype)
         x = jnp.take(e, tokens, axis=0)  # (B,S,D): feature-sharded lookup
+        if cfg.embedding_multiplier is not None:
+            x = x * cfg.embedding_multiplier
         return lac(x, "batch", "act_seq", "embed_shard")
 
     def _head(self, params, x):
@@ -100,6 +102,8 @@ class Model:
             logits = jnp.einsum(
                 "bsd,dv->bsv", x, params["lm_head"].astype(cfg.compute_dtype)
             )
+        if cfg.logits_scaling is not None:
+            logits = logits / cfg.logits_scaling
         return lac(logits, "batch", "act_seq", "logit_vocab")
 
     def encode(self, params, frames):

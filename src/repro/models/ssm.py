@@ -31,7 +31,7 @@ def mamba_spec(cfg) -> dict:
     mc = cfg.mamba
     d = cfg.d_model
     di, H, N, P = mamba_dims(cfg)
-    return {
+    spec = {
         "wz": ParamSpec((d, di), ("embed", "inner")),
         "wx": ParamSpec((d, di), ("embed", "inner")),
         "wB": ParamSpec((d, N), ("embed", "state")),
@@ -44,6 +44,9 @@ def mamba_spec(cfg) -> dict:
         "gnorm": ParamSpec((di,), ("inner",), init="ones"),
         "wo": ParamSpec((di, d), ("inner", "embed")),
     }
+    if mc.conv_bias:
+        spec["conv_b"] = ParamSpec((di + 2 * N,), ("inner",), init="zeros")
+    return spec
 
 
 def _causal_conv(xBC: jax.Array, w: jax.Array, state: Optional[jax.Array] = None):
@@ -77,7 +80,9 @@ def ssd_chunked(x, dt, a_log, Bm, Cm, chunk: int, h0=None):
     N = Bm.shape[-1]
     L = min(chunk, S)
     nc = S // L
-    assert S % L == 0, (S, L)
+    if S % L:
+        raise ValueError(f"the chunked SSD scan needs a prompt length that is "
+                         f"a multiple of the chunk: {S} tokens, chunk {L}")
 
     xb = (x * dt[..., None]).astype(jnp.float32)  # dt-scaled input
     xc = xb.reshape(Bsz, nc, L, H, P)
@@ -163,6 +168,8 @@ def apply_mamba(
     xBC = jnp.concatenate([xin, Bm, Cm], -1)
     conv_state = cache.get("conv") if cache else None
     xBC, new_conv = _causal_conv(xBC, p["conv"].astype(dt_x.dtype), conv_state)
+    if "conv_b" in p:
+        xBC = xBC + p["conv_b"].astype(dt_x.dtype)
     xBC = jax.nn.silu(xBC)
     xin, Bm, Cm = jnp.split(xBC, [di, di + N], axis=-1)
     xin = lac(xin, "batch", "seq", "inner")

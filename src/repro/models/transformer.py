@@ -60,6 +60,8 @@ def layer_spec(cfg, kind: str, is_moe: bool, decoder: bool = False) -> dict:
     if is_moe:
         spec["ln2"] = L.norm_spec(cfg)
         spec["moe"] = M.moe_spec(cfg)
+        if cfg.moe.shared_d_ff:
+            spec["shared"] = L.mlp_spec(cfg, cfg.moe.shared_d_ff)
     elif cfg.d_ff > 0:
         spec["ln2"] = L.norm_spec(cfg)
         spec["mlp"] = L.mlp_spec(cfg)
@@ -201,54 +203,73 @@ def apply_layer(
     causal: bool = True,
     max_len: Optional[int] = None,
 ):
-    """Pre-norm residual layer. Returns (x, new_cache, aux)."""
+    """Pre-norm residual layer. Returns (x, new_cache, aux).
+
+    Each sublayer's output is added to the residual stream, scaled by
+    ``cfg.residual_multiplier`` where the configuration sets one. The mixer
+    and the expert block run under named scopes (``attn``, ``mamba``,
+    ``mlstm``, ``slstm``; ``moe``, ``shared_expert``, ``mlp``) so that a
+    device trace's ops carry the layer kind."""
     aux: Dict[str, jax.Array] = {}
     h = L.apply_norm(p["ln1"], x)
     new_cache = None
-    if kind == "attn":
-        out, kvc, sf = L.apply_attention(
-            p["attn"], cfg, h, positions=positions, causal=causal,
-            cache=(cache or {}).get("kv") if cache else None, mode=mode,
-            max_len=max_len,
-        )
-        if sf:
-            add_scan_flops(sf)
-        x = x + out
-        new_cache = {"kv": kvc} if kvc is not None else None
-        if "cross" in p:  # decoder cross-attention sublayer
-            hx = L.apply_norm(p["lnx"], x)
-            cout, cc = L.apply_cross_attention(
-                p["cross"], cfg, hx, enc_out,
-                cache=(cache or {}).get("cross") if cache else None, mode=mode,
+    with jax.named_scope(kind):
+        if kind == "attn":
+            out, kvc, sf = L.apply_attention(
+                p["attn"], cfg, h, positions=positions, causal=causal,
+                cache=(cache or {}).get("kv") if cache else None, mode=mode,
+                max_len=max_len,
             )
-            x = x + cout
-            if new_cache is not None and cc is not None:
-                new_cache["cross"] = cc
-    elif kind == "mamba":
-        out, c2 = S.apply_mamba(p["mamba"], cfg, h, cache=cache, mode=mode)
-        x = x + out
-        new_cache = c2
-    elif kind == "mlstm":
-        out, c2 = X.apply_mlstm(p["mlstm"], cfg, h, cache=cache, mode=mode)
-        x = x + out
-        new_cache = c2
-    elif kind == "slstm":
-        out, c2 = X.apply_slstm(p["slstm"], cfg, h, cache=cache, mode=mode)
-        x = x + out
-        new_cache = c2
-    else:
-        raise ValueError(kind)
+            if sf:
+                add_scan_flops(sf)
+            x = _residual(cfg, x, out)
+            new_cache = {"kv": kvc} if kvc is not None else None
+            if "cross" in p:  # decoder cross-attention sublayer
+                hx = L.apply_norm(p["lnx"], x)
+                cout, cc = L.apply_cross_attention(
+                    p["cross"], cfg, hx, enc_out,
+                    cache=(cache or {}).get("cross") if cache else None,
+                    mode=mode,
+                )
+                x = _residual(cfg, x, cout)
+                if new_cache is not None and cc is not None:
+                    new_cache["cross"] = cc
+        elif kind == "mamba":
+            out, new_cache = S.apply_mamba(p["mamba"], cfg, h, cache=cache,
+                                           mode=mode)
+            x = _residual(cfg, x, out)
+        elif kind == "mlstm":
+            out, new_cache = X.apply_mlstm(p["mlstm"], cfg, h, cache=cache,
+                                           mode=mode)
+            x = _residual(cfg, x, out)
+        elif kind == "slstm":
+            out, new_cache = X.apply_slstm(p["slstm"], cfg, h, cache=cache,
+                                           mode=mode)
+            x = _residual(cfg, x, out)
+        else:
+            raise ValueError(kind)
 
     if "moe" in p:
         h2 = L.apply_norm(p["ln2"], x)
-        y, moe_aux = M.apply_moe(p["moe"], cfg, h2)
+        with jax.named_scope("moe"):
+            y, moe_aux = M.apply_moe(p["moe"], cfg, h2, mode=mode)
         aux.update(moe_aux)
-        x = x + y
+        if "shared" in p:
+            with jax.named_scope("shared_expert"):
+                y = y + L.apply_mlp(p["shared"], cfg, h2)
+        x = _residual(cfg, x, y)
     elif "mlp" in p:
         h2 = L.apply_norm(p["ln2"], x)
-        x = x + L.apply_mlp(p["mlp"], cfg, h2)
+        with jax.named_scope("mlp"):
+            x = _residual(cfg, x, L.apply_mlp(p["mlp"], cfg, h2))
     x = lac(x, "batch", "act_seq", "residual")
     return x, new_cache, aux
+
+
+def _residual(cfg, x: jax.Array, out: jax.Array) -> jax.Array:
+    if cfg.residual_multiplier is not None:
+        out = out * cfg.residual_multiplier
+    return x + out
 
 
 # ----------------------------------------------------------- period body

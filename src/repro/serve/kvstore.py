@@ -77,6 +77,31 @@ def _unpack_cache(blob: bytes):
     return __import__("jax").tree.map(lambda x: jnp.asarray(x), host)
 
 
+# cache subtrees by kind of state: a layer's recurrent state (Mamba's
+# ``ssm`` and ``conv``) is fixed-size, its attention K/V (``kv``) grows with
+# the prompt
+RECURRENT_KEYS = ("ssm", "conv")
+ATTENTION_KEYS = ("kv",)
+
+
+def _kind_bytes(cache) -> Tuple[int, int]:
+    """(recurrent, attention) bytes of a cache pytree's leaves; a leaf
+    counts by the first of its path's keys that names a kind."""
+    import jax
+
+    rec = att = 0
+    for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
+        for k in path:
+            key = getattr(k, "key", None)
+            if key in RECURRENT_KEYS:
+                rec += leaf.nbytes
+                break
+            if key in ATTENTION_KEYS:
+                att += leaf.nbytes
+                break
+    return rec, att
+
+
 def _norm_tokens(tokens) -> Tuple[int, ...]:
     """Prompt identity: any int array/sequence → flat tuple of ints."""
     arr = np.asarray(tokens).reshape(-1)
@@ -143,6 +168,11 @@ class KvStoreStats:
     evicted_bytes: int = 0  # replica bytes freed by eviction
     expirations: int = 0  # evictions whose trigger was TTL, not capacity
     evict_skipped_leased: int = 0  # victims skipped because a lease held them
+    # cache bytes by kind of state (``_kind_bytes``), always counted
+    put_recurrent_bytes: int = 0
+    put_attention_bytes: int = 0
+    fetch_recurrent_bytes: int = 0
+    fetch_attention_bytes: int = 0
 
 
 PLACEMENTS = ("prefix", "round_robin", "random")
@@ -272,6 +302,7 @@ class KvCacheStore:
                             "bytes": 0}
             with obs.span("kvstore.put.pack"):
                 blob = _pack_cache(cache)
+            rec, att = _kind_bytes(cache)
             with obs.span("kvstore.put.layout"):
                 base = f"{self.root}/{key}/s{shard}"
                 chunks = [blob[i : i + self.chunk_bytes] for i in
@@ -305,6 +336,8 @@ class KvCacheStore:
                     entry.first = np.asarray(first_token)
                 self.stats.put_chunks += len(chunks)
                 self.stats.put_bytes += len(blob)
+                self.stats.put_recurrent_bytes += rec
+                self.stats.put_attention_bytes += att
                 # capacity back-pressure: evict colder entries before the
                 # catalog commit so one persist covers insert + eviction
                 # (the fresh entry itself is protected from its own sweep)
@@ -345,12 +378,16 @@ class KvCacheStore:
                 arrivals = self._run_specs(specs, write=False)
             with obs.span("kvstore.fetch.assemble"):
                 blob = self._assemble(arrivals)[: entry.size]
+            with obs.span("kvstore.fetch.unpack"):
+                cache = _unpack_cache(blob)
+            rec, att = _kind_bytes(cache)
             with self._lock:
                 self.stats.fetches += 1
                 self.stats.fetch_bytes += len(blob)
                 self.stats.fetch_chunks += len(specs)
-            with obs.span("kvstore.fetch.unpack"):
-                return _unpack_cache(blob)
+                self.stats.fetch_recurrent_bytes += rec
+                self.stats.fetch_attention_bytes += att
+            return cache
 
     # ------------------------------------------------------------- planes
     # every chunk in flight holds a lease, and a write lease is a journal

@@ -14,6 +14,7 @@ ARCHS = [
     "glm4-9b", "granite-3-8b", "qwen3-1.7b", "mistral-nemo-12b",
     "xlstm-125m", "jamba-1.5-large-398b", "seamless-m4t-large-v2",
     "grok-1-314b", "granite-moe-3b-a800m", "phi-3-vision-4.2b",
+    "granite-4.0-h-small",
 ]
 
 
@@ -62,7 +63,8 @@ def test_train_step_no_nans(arch):
 
 
 @pytest.mark.parametrize("arch", ["glm4-9b", "xlstm-125m", "jamba-1.5-large-398b",
-                                  "seamless-m4t-large-v2", "phi-3-vision-4.2b"])
+                                  "seamless-m4t-large-v2", "phi-3-vision-4.2b",
+                                  "granite-4.0-h-small"])
 def test_decode_matches_train(arch):
     import dataclasses
 

@@ -117,3 +117,32 @@ def test_qwen3_serving_steps_fit_one_chip(one_chip):
     tok = _shape((BATCH, 1), jnp.int32, one_chip)
     compiled = _compile(make_decode_step(model), params, place(cache), tok)
     assert _device_bytes(compiled) < V5E_HBM_BYTES
+
+
+def test_granite_hybrid_cut_fits_one_chip(one_chip):
+    """granite-4.0-h-small as the benchmark serves it on one chip: one
+    pipeline stage (20 layers, two whole periods) holding 9 of each layer's
+    72 experts, every width as published. Its 8,192-token prefill and the
+    decode step after it fit 16 GB."""
+    import dataclasses
+
+    full = get_config("granite-4.0-h-small")
+    cfg = full.with_(num_layers=20,
+                     moe=dataclasses.replace(full.moe, experts_held=(0, 9)),
+                     param_dtype=jnp.bfloat16)
+    model = build_model(cfg)
+
+    def place(tree):
+        return jax.tree.map(lambda s: _shape(s.shape, s.dtype, one_chip), tree)
+
+    params = place(model.abstract_params())
+    prompt, max_len = 8192, 8192 + 32
+    batch = {"tokens": _shape((1, prompt), jnp.int32, one_chip)}
+    prefill = make_prefill_step(model, max_len)
+    _, cache = jax.eval_shape(prefill, params, batch)
+    compiled = _compile(prefill, params, batch)
+    assert _device_bytes(compiled) < V5E_HBM_BYTES
+
+    tok = _shape((1, 1), jnp.int32, one_chip)
+    compiled = _compile(make_decode_step(model), params, place(cache), tok)
+    assert _device_bytes(compiled) < V5E_HBM_BYTES
