@@ -9,9 +9,18 @@ the chosen k, and two dispatches.
   combine gather induces the EP collective. Capacity C =
   ceil(S·k·capacity_factor / E) per group; slots past it are dropped
   (standard Switch/Mixtral-style training behaviour).
-* Serving (prefill, decode): dropless. The (token, k) assignments to held
-  experts are sorted by expert and run through ``jax.lax.ragged_dot``, so
-  the expert FLOPs are those of the routed rows and no token is dropped.
+* Serving (prefill, decode): dropless, every routed token computed, by one
+  of two dispatches chosen from the static token count T = B·S:
+
+  - T·k >= E (prefill): the (token, k) assignments to held experts are
+    sorted by expert and run through ``jax.lax.ragged_dot``, so the expert
+    FLOPs are those of the routed rows and no token is dropped. It reads
+    every held expert's weights.
+  - T·k < E (decode): fewer assignments than router outputs, so most held
+    experts get no row. Each routed, held assignment is computed on its own
+    from its expert's slice of ``wi``/``wg``/``wo``, in a loop whose trip
+    count is the number of such assignments: a step reads only the experts
+    some token chose.
 
 A layer may hold a contiguous share of the experts (``MoEConfig.experts_held``,
 expert parallelism across chips): the router keeps all its outputs and its
@@ -58,6 +67,9 @@ def apply_moe(p: dict, cfg, x: jax.Array, mode: str = "train"
                             preferred_element_type=jnp.float32)
         top, eidx = jax.lax.top_k(logits, K)  # (B,S,K)
         gate = jax.nn.softmax(top, -1)
+        if x.shape[0] * x.shape[1] * K < m.num_experts:
+            with jax.named_scope("moe_routed"):
+                return _routed(p, cfg, x, gate, eidx), {}
         return _dropless(p, cfg, x, gate, eidx), {}
     if m.held != (0, m.num_experts):
         raise NotImplementedError("the training dispatch holds every expert")
@@ -117,6 +129,36 @@ def apply_moe(p: dict, cfg, x: jax.Array, mode: str = "train"
     return y, {"moe_aux": aux, "moe_z": zloss}
 
 
+def _stack(p: dict, name: str) -> jax.Array:
+    """The layer's (held, ., .) stack of expert matrix ``name``. Under the
+    serving period scan ``p`` holds every period's stack and its
+    ``period`` (``transformer.apply_stack``)."""
+    w = p[name]
+    if "period" in p:
+        w = jax.lax.dynamic_index_in_dim(w, p["period"], keepdims=False)
+    return w
+
+
+def _expert(p: dict, name: str, e: jax.Array, dtype) -> jax.Array:
+    """Expert ``e``'s matrix ``name``, one slice of the whole stack, so
+    that XLA feeds it to the dot where a loop over a period's stack would
+    copy that stack, every expert, first."""
+    w = p[name]
+    lead = (p["period"], e) if "period" in p else (e,)
+    w = jax.lax.dynamic_slice(w, lead + (0, 0), (1,) * len(lead) + w.shape[-2:])
+    return w.reshape(w.shape[-2:]).astype(dtype)
+
+
+def _held_order(cfg, eidx: jax.Array):
+    """Each (token, k) assignment's held expert (local id; ``n`` for one
+    held elsewhere) and the assignments sorted by it, held first."""
+    first, n = cfg.moe.held
+    local = eidx.reshape(-1) - first
+    held = (local >= 0) & (local < n)
+    group = jnp.where(held, local, n).astype(jnp.int32)  # n: not held here
+    return held, group, jnp.argsort(group, stable=True)
+
+
 def _dropless(p: dict, cfg, x: jax.Array, gate: jax.Array, eidx: jax.Array
               ) -> jax.Array:
     """The held experts' part of the layer, every routed token computed.
@@ -127,30 +169,61 @@ def _dropless(p: dict, cfg, x: jax.Array, gate: jax.Array, eidx: jax.Array
     bound the held assignments and ``ragged_dot`` computes only the
     ``sum(group_sizes)`` rows that were routed."""
     B, S, D = x.shape
-    first, n = cfg.moe.held
+    n = cfg.moe.held[1]
     K = eidx.shape[-1]
     T = B * S
-    local = eidx.reshape(T * K) - first
-    held = (local >= 0) & (local < n)
-    group = jnp.where(held, local, n).astype(jnp.int32)  # n: not held here
+    held, group, order = _held_order(cfg, eidx)
     rows = T * min(K, n)
-    order = jnp.argsort(group, stable=True)[:rows]
+    order = order[:rows]
     sizes = jnp.bincount(group, length=n + 1)[:n].astype(jnp.int32)
     tok = order // K
     xs = x.reshape(T, D)[tok]  # (rows, D)
-    h = jax.lax.ragged_dot(xs, p["wi"].astype(x.dtype), sizes)
+    h = jax.lax.ragged_dot(xs, _stack(p, "wi").astype(x.dtype), sizes)
     if "wg" in p:
-        g = jax.lax.ragged_dot(xs, p["wg"].astype(x.dtype), sizes)
+        g = jax.lax.ragged_dot(xs, _stack(p, "wg").astype(x.dtype), sizes)
         h = jax.nn.silu(g) * h
     else:
         h = jax.nn.gelu(h)
-    ys = jax.lax.ragged_dot(h, p["wo"].astype(x.dtype), sizes,
+    ys = jax.lax.ragged_dot(h, _stack(p, "wo").astype(x.dtype), sizes,
                             preferred_element_type=jnp.float32)
     # rows past the routed ones belong to no group: select, never multiply,
     # so nothing they hold can reach the sum
     w = gate.reshape(T * K)[order]
     ys = jnp.where(held[order][:, None], ys * w[:, None], 0.0)
     y = jnp.zeros((T, D), jnp.float32).at[tok].add(ys)
+    return y.astype(x.dtype).reshape(B, S, D)
+
+
+def _routed(p: dict, cfg, x: jax.Array, gate: jax.Array, eidx: jax.Array
+            ) -> jax.Array:
+    """``_dropless`` for a few tokens, reading only the routed experts.
+
+    The loop runs once per held assignment (the sorted ones first), each
+    time a matrix-vector product with its expert's slice of the weights, in
+    ``_dropless``'s precision: bf16 operands, ``h`` in the activation
+    dtype, the ``wo`` product and the gate-weighted sum in f32. A token
+    with no held assignment gets exactly 0."""
+    B, S, D = x.shape
+    K = eidx.shape[-1]
+    T = B * S
+    held, group, order = _held_order(cfg, eidx)
+    xt = x.reshape(T, D)
+    w = gate.reshape(T * K)
+
+    def one(i, y):
+        a = order[i]
+        e, t = group[a], a // K
+        xi = jax.lax.dynamic_index_in_dim(xt, t)  # (1, D)
+        h = xi @ _expert(p, "wi", e, x.dtype)
+        if "wg" in p:
+            h = jax.nn.silu(xi @ _expert(p, "wg", e, x.dtype)) * h
+        else:
+            h = jax.nn.gelu(h)
+        yi = jnp.dot(h, _expert(p, "wo", e, x.dtype),
+                     preferred_element_type=jnp.float32)
+        return y.at[t].add(yi[0] * w[a])
+
+    y = jax.lax.fori_loop(0, held.sum(), one, jnp.zeros((T, D), jnp.float32))
     return y.astype(x.dtype).reshape(B, S, D)
 
 

@@ -266,6 +266,32 @@ def apply_layer(
     return x, new_cache, aux
 
 
+def _hold_expert_stacks(stacked: tuple):
+    """Serving under the period scan: each MoE layer's expert matrices kept
+    whole, out of what the scan slices by period. A layer that loops over
+    its routed experts then slices one expert from the whole stack; a loop
+    over one period's slice would make XLA copy that slice, every expert,
+    each period. Returns the rest of the period's parameters and, per
+    layer, its whole expert stacks ({} for a layer with none); () where no
+    layer has experts."""
+    rest, experts = [], []
+    for lp in stacked:
+        moe = lp.get("moe", {})
+        mats = {k: v for k, v in moe.items() if k != "router"}
+        if mats:
+            lp = dict(lp, moe={"router": moe["router"]})
+        rest.append(lp)
+        experts.append(mats)
+    if not any(experts):
+        return stacked, ()
+    return tuple(rest), tuple(experts)
+
+
+def _with_expert_stacks(pp: tuple, experts: tuple, period) -> tuple:
+    return tuple(dict(lp, moe=dict(lp["moe"], **mats, period=period))
+                 if mats else lp for lp, mats in zip(pp, experts))
+
+
 def _residual(cfg, x: jax.Array, out: jax.Array) -> jax.Array:
     if cfg.residual_multiplier is not None:
         out = out * cfg.residual_multiplier
@@ -334,24 +360,21 @@ def apply_stack(
         stacked = params["scan"]
         n = jax.tree.leaves(stacked)[0].shape[0]
         pc_stacked = caches["scan"] if caches is not None else None
+        experts: tuple = ()
+        if mode != "train":
+            stacked, experts = _hold_expert_stacks(stacked)
 
         def body(carry, xs):
-            pp, pc = xs
+            pp, pc, period = xs
+            if experts:
+                pp = _with_expert_stacks(pp, experts, period)
             with scan_scope(n):
                 y, ncs, aux = period_fn(carry, pp, pc)
             return y, (ncs if want_cache else None, aux)
 
-        xs = (stacked, pc_stacked)
-        if pc_stacked is None:
-            # supply a None-tree aligned leaf-wise: use per-iteration index only
-            def body_nc(carry, pp):
-                with scan_scope(n):
-                    y, ncs, aux = period_fn(carry, pp, None)
-                return y, (ncs if want_cache else None, aux)
-
-            x, (ncs, auxs) = jax.lax.scan(body_nc, x, stacked)
-        else:
-            x, (ncs, auxs) = jax.lax.scan(body, x, xs)
+        periods = jnp.arange(n, dtype=jnp.int32) if experts else None
+        xs = (stacked, pc_stacked, periods)
+        x, (ncs, auxs) = jax.lax.scan(body, x, xs)
         aux = jax.tree.map(lambda a: a.sum(0), auxs)
         new_caches = {"scan": ncs} if want_cache else None
     else:

@@ -119,11 +119,12 @@ def test_qwen3_serving_steps_fit_one_chip(one_chip):
     assert _device_bytes(compiled) < V5E_HBM_BYTES
 
 
-def test_granite_hybrid_cut_fits_one_chip(one_chip):
+def _granite_cut(one_chip, prompt: int):
     """granite-4.0-h-small as the benchmark serves it on one chip: one
     pipeline stage (20 layers, two whole periods) holding 9 of each layer's
-    72 experts, every width as published. Its 8,192-token prefill and the
-    decode step after it fit 16 GB."""
+    72 experts, every width as published. Returns the model, its
+    parameters' shapes and its prefill step for a ``prompt``-token batch
+    with 32 tokens to serve."""
     import dataclasses
 
     full = get_config("granite-4.0-h-small")
@@ -131,18 +132,40 @@ def test_granite_hybrid_cut_fits_one_chip(one_chip):
                      moe=dataclasses.replace(full.moe, experts_held=(0, 9)),
                      param_dtype=jnp.bfloat16)
     model = build_model(cfg)
-
-    def place(tree):
-        return jax.tree.map(lambda s: _shape(s.shape, s.dtype, one_chip), tree)
-
-    params = place(model.abstract_params())
-    prompt, max_len = 8192, 8192 + 32
+    params = jax.tree.map(lambda s: _shape(s.shape, s.dtype, one_chip),
+                          model.abstract_params())
     batch = {"tokens": _shape((1, prompt), jnp.int32, one_chip)}
-    prefill = make_prefill_step(model, max_len)
+    return model, params, make_prefill_step(model, prompt + 32), batch
+
+
+def _decode_compiled(one_chip, model, params, prefill, batch):
     _, cache = jax.eval_shape(prefill, params, batch)
+    cache = jax.tree.map(lambda s: _shape(s.shape, s.dtype, one_chip), cache)
+    tok = _shape((1, 1), jnp.int32, one_chip)
+    return _compile(make_decode_step(model), params, cache, tok)
+
+
+def test_granite_hybrid_cut_fits_one_chip(one_chip):
+    """The cut's 8,192-token prefill and the decode step after it fit
+    16 GB."""
+    model, params, prefill, batch = _granite_cut(one_chip, 8192)
     compiled = _compile(prefill, params, batch)
     assert _device_bytes(compiled) < V5E_HBM_BYTES
-
-    tok = _shape((1, 1), jnp.int32, one_chip)
-    compiled = _compile(make_decode_step(model), params, place(cache), tok)
+    compiled = _decode_compiled(one_chip, model, params, prefill, batch)
     assert _device_bytes(compiled) < V5E_HBM_BYTES
+
+
+def test_granite_decode_copies_no_expert_stack(one_chip):
+    """The decode step reads each routed expert from the whole stacked
+    parameter, inside the loop over the routed assignments. A loop over one
+    period's slice of the stack would make XLA copy the slice, all 9
+    experts (169.9 MB a layer), every period: no while loop may take such a
+    slice, and the step's temporaries stay below one slice."""
+    model, params, prefill, batch = _granite_cut(one_chip, 4096)
+    compiled = _decode_compiled(one_chip, model, params, prefill, batch)
+    stack_bytes = 3 * 9 * 4096 * 768 * 2  # wi, wg, wo of 9 held experts
+    assert compiled.memory_analysis().temp_size_in_bytes < stack_bytes
+    loops = [ln for ln in compiled.as_text().splitlines() if " while(" in ln]
+    assert any("[2,9,4096,768]" in ln for ln in loops)  # the whole stack
+    assert not any("[9,4096,768]" in ln or "[9,768,4096]" in ln
+                   for ln in loops)
