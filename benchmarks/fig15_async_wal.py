@@ -143,7 +143,7 @@ def part_c():
     # known durable: the watermark after drain + the last metadata commit.
     db.wal.wait_durable()
     fs.flush_metadata()
-    # a submit_many-style write lease still outstanding at crash time
+    # a batched job's write lease still outstanding at crash time
     fs.create("/orphaned-output")
     fs.fallocate("/orphaned-output", 64 * 1024)
     # reprolint: allow[lease-raw] deliberate orphan: crash-recovery bench needs a never-released grant

@@ -1,4 +1,4 @@
-"""Benchmark harness: one module per paper figure (+ the roofline report).
+"""Benchmark harness: one module per paper figure.
 Prints ``name,value,derived`` CSV rows; claim checks appear as
 ``claim/<name>,PASS|FAIL``. Usage::
 
@@ -38,7 +38,6 @@ MODULES = [
     "benchmarks.fig20_kv_serving",
     "benchmarks.fig21_pushdown",
     "benchmarks.fig22_memtier",
-    "benchmarks.roofline_report",
 ]
 
 SMOKE_MODULES = [
@@ -52,7 +51,6 @@ SMOKE_MODULES = [
     "benchmarks.fig20_kv_serving",
     "benchmarks.fig21_pushdown",
     "benchmarks.fig22_memtier",
-    "benchmarks.roofline_report",
 ]
 
 USAGE = """\

@@ -1,5 +1,5 @@
 """Batched serving demo: prefill + greedy decode on a reduced config, using
-the same serve_step the decode shape-cells lower for the dry-run.
+the serving steps of ``repro.serve.step``.
 
     PYTHONPATH=src python examples/serving.py
 """

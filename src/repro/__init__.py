@@ -2,8 +2,7 @@
 
 Two planes:
   * compute plane: model substrate + pjit/shard_map distribution for the 10
-    assigned architectures (``repro.models``, ``repro.train``, ``repro.serve``,
-    ``repro.launch``).
+    assigned architectures (``repro.models``, ``repro.train``, ``repro.serve``).
   * I/O plane: the paper's contribution — OffloadFS / OffloadDB / OffloadPrep
     (``repro.core``, ``repro.data``) with a calibrated DES for benchmarks
     (``repro.sim``).

@@ -39,7 +39,6 @@ def make():
         logits_scaling=16.0,
         tie_embeddings=True,
         max_seq_len=131072,
-        sub_quadratic=True,
         scan_layers=True,
     )
 

@@ -5,7 +5,7 @@ per 8-layer period), MoE every other layer. [arXiv:2403.19887]
 TPU adaptation: Mamba blocks run the chunked SSD (Mamba-2) matmul
 formulation (DESIGN.md §3) — d_state=64, head_dim=64 — instead of the CUDA
 selective-scan; hybrid attention layers use the standard GQA path and are
-the only KV-cache consumers (long_500k lives mostly in O(1) SSM state).
+the only KV-cache consumers (long context lives mostly in O(1) SSM state).
 """
 from repro.models.config import MambaConfig, ModelConfig, MoEConfig, register
 
@@ -27,7 +27,6 @@ def make():
         moe_every=2,
         moe_offset=1,
         mamba=MambaConfig(d_state=64, d_conv=4, expand=2, head_dim=64, chunk=256),
-        sub_quadratic=True,
         scan_layers=True,
     )
 

@@ -4,8 +4,7 @@ d_ff=8192 vocab=256206. [arXiv:2308.11596]
 The modality frontend is a STUB per the assignment: ``input_specs()``
 supplies precomputed audio-frame embeddings (B, 3200, d_model) as encoder
 input; the transformer backbone (24 enc + 24 dec layers, cross-attention)
-is fully modeled. Decoder has a decode step (decode_32k runs); long_500k is
-skipped (full attention)."""
+is fully modeled. Decoder has a decode step."""
 from repro.models.config import ModelConfig, register
 
 

@@ -18,7 +18,6 @@ def make():
         block_pattern=("mlstm", "mlstm", "mlstm", "slstm"),
         xlstm=XLSTMConfig(),
         rotary_pct=0.0,  # recurrent blocks: no RoPE
-        sub_quadratic=True,
         scan_layers=True,
     )
 

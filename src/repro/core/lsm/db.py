@@ -693,8 +693,9 @@ class OffloadDB:
 
     def _run_jobs(self, jobs) -> None:
         """Execute prepared jobs, filling job["results"]/job["where"].
-        When ≥2 jobs are offloadable they go out via submit_many — one wire
-        batch per shard, shards served concurrently; otherwise serial."""
+        When ≥2 jobs are offloadable they go out in one ``submit(specs)`` —
+        one wire batch per shard, shards served concurrently; otherwise
+        serial."""
         parallel = (self.off is not None and len(jobs) > 1
                     and all(self._offload_ok(j["task"], j["level"]) for j in jobs))
         if parallel:
@@ -915,7 +916,7 @@ class OffloadDB:
         """Rebuild from MANIFEST + WAL replay after a crash/restart.
 
         Recovery consults the lease journal first: write leases orphaned by
-        the crash (in-flight WAL segments, submit_many flush/compaction
+        the crash (in-flight WAL segments, batched flush/compaction
         grants) are fenced and reclaimed WITHOUT scanning, so the replay
         below can read those blocks. WAL replay then trusts only the intact
         device prefix — with async shipping the durability watermark at

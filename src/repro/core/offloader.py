@@ -33,16 +33,13 @@ and ONE submission entry point:
     admission-rejected or wire-failed share once on the least-loaded
     *other* target before the local fallback runs.
 
-Deprecated shims kept for pre-consolidation callers: ``submit_task`` (one
-task, one coalesced wire message — ``coalesce=False`` keeps the legacy
-3-message handshake for comparison; also reachable as
-``submit("task", *args, ...)``), ``submit_async`` (single-task future) and
-``submit_many`` (barrier batch: all-or-nothing on wire failure).
+The pre-spec single-task spelling ``submit("task", *args, ...)`` blocks on
+one coalesced wire message (``coalesce=False`` keeps the legacy 3-message
+handshake for comparison).
 """
 from __future__ import annotations
 
 import threading
-import warnings
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
@@ -64,13 +61,13 @@ class OffloadStats:
     rejected: int = 0
     ran_local: int = 0
     rerouted: int = 0  # admission pushback retried on another target
-    batches: int = 0  # submit_many wire batches sent
+    batches: int = 0  # wire batches sent (one per target per submit)
     affinity_routed: int = 0  # tasks routed to the shard owning their extents
     by_target: Dict[str, int] = field(default_factory=dict)
     rejected_by_target: Dict[str, int] = field(default_factory=dict)
 
 
-# submit_async resolves to (result, where_ran); same semantics as the
+# a submitted spec resolves to (result, where_ran); same semantics as the
 # fabric's future, so reuse it rather than maintaining a twin
 OffloadFuture = RpcFuture
 
@@ -90,7 +87,7 @@ class TaskOffloader:
             raise ValueError(f"unknown lb_policy {lb_policy!r}")
         self.lb_policy = lb_policy
         # coalesce=False keeps the legacy 3-message handshake per task and
-        # unbatched submit_many — the Fig. 14 baseline
+        # unbatched spec submission — the Fig. 14 baseline
         self.coalesce = coalesce
         self.targets: List[str] = list(targets) if targets else [storage_node]
         self._local_engine = OffloadEngine(fs, node=node, enable_cache=False)
@@ -335,8 +332,10 @@ class TaskOffloader:
             pushback/wire-failure reroute path (spec-level value wins).
 
         Legacy form: ``submit("task", *args, read_extents=..., ...)`` —
-        the pre-consolidation single-task signature, kept as a shim and
-        routed to :meth:`submit_task`."""
+        the pre-consolidation single-task signature: one task, one
+        coalesced wire message, blocking; returns ``(result, where_ran)``.
+        The initiator quiesces on the leased write set for the duration
+        (no DLM — lease discipline instead)."""
         if isinstance(task_or_specs, str):
             if stream or async_ or reroute:
                 raise TypeError(
@@ -367,22 +366,6 @@ class TaskOffloader:
         if first_exc is not None:
             raise first_exc
         return results[0] if single else results
-
-    def submit_task(
-        self,
-        task: str,
-        *args,
-        **kwargs,
-    ):
-        """Deprecated shim (pre-consolidation API, kept so existing callers
-        run unchanged — use :meth:`submit`): offload one `task` to `target`
-        (default: load-balanced pick) and block. Returns (result,
-        where_ran). The initiator quiesces on the leased write set for the
-        duration (no DLM — lease discipline instead)."""
-        warnings.warn(
-            "TaskOffloader.submit_task is deprecated; use "
-            "TaskOffloader.submit", DeprecationWarning, stacklevel=2)
-        return self._submit_task(task, *args, **kwargs)
 
     def _submit_task(
         self,
@@ -434,187 +417,6 @@ class TaskOffloader:
             if not ok:
                 self._end(dst, "error", nb)
             self.fs.release_lease(lease)
-
-    def submit_async(
-        self,
-        task: str,
-        *args,
-        **kwargs,
-    ) -> OffloadFuture:
-        """Deprecated shim (use ``submit(spec, async_=True)``): non-blocking
-        single-task submit. The lease stays outstanding (the initiator
-        keeps quiescing on the write set) until the future resolves; the
-        rejected-task fallback runs at resolution. Always a single
-        coalesced wire message — async submission has no legacy-handshake
-        form, so ``coalesce=False`` offloaders still coalesce here."""
-        warnings.warn(
-            "TaskOffloader.submit_async is deprecated; use "
-            "TaskOffloader.submit(spec, async_=True)",
-            DeprecationWarning, stacklevel=2)
-        return self._submit_async(task, *args, **kwargs)
-
-    def _submit_async(
-        self,
-        task: str,
-        *args,
-        read_extents: Sequence[Extent] = (),
-        write_extents: Sequence[Extent] = (),
-        target: Optional[str] = None,
-        mtime: float = 0.0,
-        bypass_cache: bool = False,
-        **kwargs,
-    ) -> OffloadFuture:
-        dst = target or self._route(read_extents, write_extents)
-        # reprolint: allow[lease-raw] released in the RPC completion callback, not in this scope
-        lease = self.fs.grant_lease(read_extents, write_extents)
-        nb = self._lease_blocks(lease)
-        self._begin(dst, nb)
-        ofut = OffloadFuture()
-        # the router's cancellation path needs the in-flight lease (to
-        # revoke it through the journal) and the destination (telemetry)
-        ofut.lease = lease
-        ofut.target = dst
-        wire_fut: RpcFuture = self.fabric.call_async(
-            self.node, dst, "submit_task", self.node, task,
-            self._wire(lease), args, kwargs, mtime, bypass_cache,
-        )
-
-        def _done(f: RpcFuture):
-            try:
-                exc = f.exception()
-                if exc is not None:
-                    self._end(dst, "error", nb)
-                    ofut.set_exception(exc)
-                    return
-                status, result = f.result()
-                if status == "ok":
-                    self._end(dst, "offloaded", nb)
-                    ofut.set_result((result, dst))
-                    return
-                self._end(dst, "rejected", nb)
-                try:
-                    result = self._run_local(task, lease, args, kwargs, mtime)
-                except BaseException as e:  # noqa: BLE001
-                    ofut.set_exception(e)
-                    return
-                ofut.set_result((result, self.node))
-            finally:
-                self.fs.release_lease(lease)
-
-        wire_fut.add_done_callback(_done)
-        return ofut
-
-    def submit_many(self, specs: Sequence[dict], *,
-                    stream: bool = False) -> List[Any]:
-        """Deprecated shim (use ``submit(specs)`` / ``submit(specs,
-        stream=True)``). Load-balanced batch submission: each spec is a dict with keys
-        ``task``, ``args`` (tuple), plus optional ``kwargs``,
-        ``read_extents``, ``write_extents``, ``target``, ``mtime``,
-        ``bypass_cache``, ``reroute`` (stream only). One wire message per
-        distinct target (``call_batch``), targets served concurrently;
-        rejected sub-tasks fall back to local execution. Returns
-        [(result, where)] in input order. If any wire batch fails the
-        whole call raises after all leases are released — results of
-        sub-tasks that did complete are discarded, so callers must treat
-        the batch as all-or-nothing.
-
-        ``stream=True`` is the streaming-completion plane: the same
-        per-target wire batching, but the call returns immediately with
-        one ``OffloadFuture`` per spec (resolving to ``(result, where)``)
-        instead of a barrier — shares on a fast target resolve while a
-        slow target still computes. Leases are released per share at
-        resolution; a wire failure resolves only that target's futures
-        (with the exception), not the whole batch. A streamed spec with
-        ``reroute=True`` retries admission pushback once on the
-        least-loaded other target before falling back local."""
-        warnings.warn(
-            "TaskOffloader.submit_many is deprecated; use "
-            "TaskOffloader.submit(specs)", DeprecationWarning, stacklevel=2)
-        if stream:
-            return self._submit_many_stream(specs)
-        if not specs:
-            return []
-        if not self.coalesce:  # legacy plane: one handshake per task, serial
-            return [
-                self.submit(
-                    s["task"], *tuple(s.get("args", ())),
-                    read_extents=s.get("read_extents", ()),
-                    write_extents=s.get("write_extents", ()),
-                    target=s.get("target"), mtime=s.get("mtime", 0.0),
-                    bypass_cache=s.get("bypass_cache", False),
-                    coalesce=False, **dict(s.get("kwargs", {})),
-                )
-                for s in specs
-            ]
-        plan = []  # (idx, spec, dst, lease)
-        try:
-            for idx, s in enumerate(specs):
-                dst = s.get("target") or self._route(
-                    s.get("read_extents", ()), s.get("write_extents", ())
-                )
-                lease = self.fs.grant_lease(
-                    s.get("read_extents", ()), s.get("write_extents", ())
-                )
-                self._begin(dst, self._lease_blocks(lease))
-                plan.append((idx, s, dst, lease))
-        except BaseException:
-            # e.g. LeaseViolation mid-batch: unwind what was granted
-            for _, _, d, lease in plan:
-                self._end(d, "error", self._lease_blocks(lease))
-                self.fs.release_lease(lease)
-            raise
-        groups: Dict[str, List[tuple]] = {}
-        for entry in plan:
-            groups.setdefault(entry[2], []).append(entry)
-        futures = []
-        for dst, entries in groups.items():  # insertion order: deterministic
-            calls = [
-                ("submit_task",
-                 (self.node, s["task"], self._wire(lease),
-                  tuple(s.get("args", ())), dict(s.get("kwargs", {})),
-                  s.get("mtime", 0.0), s.get("bypass_cache", False)),
-                 {})
-                for (_, s, _, lease) in entries
-            ]
-            futures.append((dst, entries, self.fabric.call_batch_async(
-                self.node, dst, calls)))
-            with self._lock:
-                self.stats.batches += 1
-        out: List[Any] = [None] * len(specs)
-        pending_local = []  # rejected: run after all wires resolve
-        first_exc: Optional[BaseException] = None
-        for dst, entries, fut in futures:
-            try:
-                results = fut.result()
-            except BaseException as e:  # noqa: BLE001
-                for (_, _, _, lease) in entries:
-                    self._end(dst, "error", self._lease_blocks(lease))
-                    self.fs.release_lease(lease)
-                if first_exc is None:
-                    first_exc = e
-                continue
-            for (idx, s, _, lease), (status, result) in zip(entries, results):
-                if status == "ok":
-                    self._end(dst, "offloaded", self._lease_blocks(lease))
-                    out[idx] = (result, dst)
-                    self.fs.release_lease(lease)
-                else:
-                    self._end(dst, "rejected", self._lease_blocks(lease))
-                    pending_local.append((idx, s, lease))
-        if first_exc is not None:
-            for (_, _, lease) in pending_local:
-                self.fs.release_lease(lease)
-            raise first_exc
-        for idx, s, lease in sorted(pending_local):
-            try:
-                result = self._run_local(
-                    s["task"], lease, tuple(s.get("args", ())),
-                    dict(s.get("kwargs", {})), s.get("mtime", 0.0),
-                )
-                out[idx] = (result, self.node)
-            finally:
-                self.fs.release_lease(lease)
-        return out
 
     # ------------------------------------------------- streaming submission
     def _fallback_local(self, spec: dict, lease: Lease,
@@ -717,10 +519,19 @@ class TaskOffloader:
         fut.add_done_callback(_done)
 
     def _submit_many_stream(self, specs: Sequence[dict]) -> List[OffloadFuture]:
-        """submit_many's streaming plane — see its docstring. On the
-        legacy (``coalesce=False``) plane each spec runs through the
-        3-message ``submit`` serially and its future resolves immediately
-        (the Fig. 14 baseline has no async form)."""
+        """The spec plane behind :meth:`submit`: specs are routed and
+        leased, then sent as ONE wire message per distinct target
+        (``call_batch_async``), targets served concurrently. Returns one
+        ``OffloadFuture`` per spec, resolving to ``(result, where)`` —
+        shares on a fast target resolve while a slow target still
+        computes. Leases are released per share at resolution; a wire
+        failure resolves only that target's futures (with the exception).
+        Rejected shares fall back to local execution; a spec with
+        ``reroute=True`` first retries admission pushback or a wire
+        failure once on the least-loaded other target. On the legacy
+        (``coalesce=False``) plane each spec runs through the 3-message
+        ``submit`` serially and its future resolves immediately (the
+        Fig. 14 baseline has no async form)."""
         futs = [OffloadFuture() for _ in specs]
         if not specs:
             return futs
@@ -749,8 +560,8 @@ class TaskOffloader:
                     s.get("read_extents", ()), s.get("write_extents", ())
                 )
                 self._begin(dst, self._lease_blocks(lease))
-                # same contract as submit_async: the router's cancellation
-                # path revokes the in-flight lease through the journal
+                # the router's cancellation path revokes the in-flight
+                # lease through the journal
                 futs[idx].lease = lease
                 futs[idx].target = dst
                 plan.append((idx, s, dst, lease))

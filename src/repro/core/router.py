@@ -89,7 +89,8 @@ class RouterStats:
 class OffloadRequest:
     """Handle for one routed task: a future plus ``cancel()``.
 
-    The future resolves to ``(result, where_ran)`` like ``submit_async``,
+    The future resolves to ``(result, where_ran)`` like a spec's future
+    from ``TaskOffloader.submit``,
     or raises ``RequestCancelled`` / ``OverloadShed`` / the wire error.
     """
 
@@ -502,7 +503,7 @@ class ClusterRouter:
         inner = req._inner
         if inner is not None and getattr(inner, "lease", None) is not None:
             # revoke authority mid-flight: journaled release (idempotent —
-            # the submit_async completion path may release again, harmless)
+            # the offloader's completion path may release again, harmless)
             self.fs.release_lease(inner.lease)
         req.future.set_exception(RequestCancelled("cancelled in flight"))
         self.pump()

@@ -104,7 +104,7 @@ class OffloadPrep:
     def share_spec(self, target: str, ids: Sequence[int],
                    paths: Sequence[str], *, epoch_seed: int = 0,
                    reroute: bool = False) -> dict:
-        """A ``TaskOffloader.submit_many`` spec for one remote share."""
+        """A ``TaskOffloader.submit`` spec for one remote share."""
         args, extents = [], []
         for i in ids:
             a, e = self._image_arg(paths[i], self._image_seed(epoch_seed, i))

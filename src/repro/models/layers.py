@@ -7,9 +7,7 @@ installs the resolver; without a mesh these are no-ops).
 Attention has two paths:
   * einsum path (exact HLO FLOPs) for seq <= FLASH_THRESHOLD and all decode;
   * chunked online-softmax path (lax.scan over KV blocks) above it — the jnp
-    twin of kernels/flash_attention; O(S·block) memory. Scan-body FLOPs are
-    under-counted by XLA cost_analysis — models report the analytic correction
-    via ``scan_flops`` bookkeeping (see roofline.py).
+    twin of kernels/flash_attention; O(S·block) memory.
 """
 from __future__ import annotations
 
@@ -252,13 +250,6 @@ def _flash_attention_qchunked(qg, k, v, *, scale, causal, softcap,
     return outs.transpose(1, 0, 2, 3, 4, 5).reshape(B, Sq, KV, G, D)
 
 
-def attention_scan_flops(B, Sq, Sk, H, D, causal: bool) -> float:
-    """Analytic FLOPs of the chunked-attention scan (QK^T + PV), for the
-    cost_analysis scan-body correction. Causal halves the effective area."""
-    area = Sq * Sk * (0.5 if causal else 1.0)
-    return 4.0 * B * H * area * D
-
-
 def apply_attention(
     p: dict,
     cfg,
@@ -271,7 +262,7 @@ def apply_attention(
     mode: str = "train",
     max_len: Optional[int] = None,  # prefill: KV-buffer headroom (>= S)
 ):
-    """Returns (out, new_cache, scan_flops)."""
+    """Returns (out, new_cache)."""
     B, S, _ = x.shape
     q = jnp.einsum("bsd,dkgh->bskgh", x, p["wq"].astype(x.dtype))  # (B,S,KV,G,hd)
     src = x if kv_src is None else kv_src
@@ -289,7 +280,6 @@ def apply_attention(
     v = lac(v, "batch", None, "kv_heads", None)
 
     new_cache = None
-    scan_flops = 0.0
     if mode == "decode":
         assert cache is not None and S == 1
         idx = cache["len"]  # (B,) current lengths
@@ -319,7 +309,6 @@ def apply_attention(
                 q, k, v, scale=attention_scale(cfg), causal=causal,
                 softcap=cfg.attn_logit_softcap,
             )
-            scan_flops = attention_scan_flops(B, S, S, cfg.num_heads, cfg.head_dim, causal)
         else:
             out = _einsum_attention(
                 q, k, v, scale=attention_scale(cfg), causal=causal,
@@ -327,7 +316,7 @@ def apply_attention(
             )
     out = lac(out, "batch", None, "kv_heads", "q_per_kv", None)
     y = jnp.einsum("bskgd,kgdm->bsm", out, p["wo"].astype(x.dtype))
-    return y, new_cache, scan_flops
+    return y, new_cache
 
 
 def apply_cross_attention(p, cfg, x, enc_out, *, cache=None, mode="train"):

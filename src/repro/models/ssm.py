@@ -137,14 +137,6 @@ def ssd_chunked(x, dt, a_log, Bm, Cm, chunk: int, h0=None):
     return y, h_last
 
 
-def ssd_scan_flops(B, S, H, P, N, chunk) -> float:
-    """Analytic FLOPs for pieces inside the associative scan (tiny) — the
-    heavy einsums are outside any scan, so no correction needed. Returned for
-    completeness."""
-    nc = max(S // chunk, 1)
-    return 2.0 * B * nc * H * N * P  # combine muladds (upper bound per pass)
-
-
 def apply_mamba(
     p: dict,
     cfg,

@@ -20,7 +20,6 @@ from repro.models import layers as L
 from repro.models import moe as M
 from repro.models import ssm as S
 from repro.models import xlstm as X
-from repro.models.accounting import add_scan_flops, scan_scope
 from repro.models.schema import ParamSpec
 from repro.sharding import lac
 
@@ -215,13 +214,11 @@ def apply_layer(
     new_cache = None
     with jax.named_scope(kind):
         if kind == "attn":
-            out, kvc, sf = L.apply_attention(
+            out, kvc = L.apply_attention(
                 p["attn"], cfg, h, positions=positions, causal=causal,
                 cache=(cache or {}).get("kv") if cache else None, mode=mode,
                 max_len=max_len,
             )
-            if sf:
-                add_scan_flops(sf)
             x = _residual(cfg, x, out)
             new_cache = {"kv": kvc} if kvc is not None else None
             if "cross" in p:  # decoder cross-attention sublayer
@@ -368,8 +365,7 @@ def apply_stack(
             pp, pc, period = xs
             if experts:
                 pp = _with_expert_stacks(pp, experts, period)
-            with scan_scope(n):
-                y, ncs, aux = period_fn(carry, pp, pc)
+            y, ncs, aux = period_fn(carry, pp, pc)
             return y, (ncs if want_cache else None, aux)
 
         periods = jnp.arange(n, dtype=jnp.int32) if experts else None

@@ -4,8 +4,7 @@ and sLSTM (scalar memory, true recurrence with exponential gating).
 mLSTM is computed in a stabilized chunkwise-parallel form (lax.scan over
 chunks; per-pair weights have non-positive exponents by construction of the
 running stabilizer). sLSTM is a genuine RNN (block-diagonal recurrent
-weights) — lax.scan over time. Scan-body FLOPs are declared to
-``accounting.add_scan_flops`` for the roofline correction.
+weights) — lax.scan over time.
 """
 from __future__ import annotations
 
@@ -14,7 +13,6 @@ import math
 import jax
 import jax.numpy as jnp
 
-from repro.models.accounting import add_scan_flops
 from repro.models.schema import ParamSpec
 from repro.sharding import lac
 
@@ -100,7 +98,6 @@ def mlstm_cell(q, k, v, logi, logf, state=None, chunk=MLSTM_CHUNK):
         return st, h
 
     state, hs = jax.lax.scan(step, state, (qc, kc, vc, lic, lfc))
-    add_scan_flops(2.0 * B * H * Ssz * L * (3 * P + 2))  # QK^T + WV + state einsums
     h = hs.transpose(1, 0, 3, 2, 4).reshape(B, Ssz, H, P)
     return h, state
 
@@ -248,7 +245,6 @@ def apply_slstm(p, cfg, x, *, cache=None, mode="train"):
             return carry, carry[0]
 
         st, hs = jax.lax.scan(step, st, wx.transpose(1, 0, 2))
-        add_scan_flops(2.0 * B * S * 4 * H * dh * dh)
         hs = hs.transpose(1, 0, 2, 3)  # (B,S,H,dh)
         new_cache = {"conv": new_conv, "slstm": st} if mode == "prefill" else None
 

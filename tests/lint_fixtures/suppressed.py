@@ -8,5 +8,5 @@ def standalone_comment(fs, extents):
     return lease
 
 
-def same_line(off, spec):
-    return off.submit_task(spec)  # reprolint: allow[deprecated-api] fixture: same-line suppression
+def same_line(fs, extents):
+    return fs.grant_lease((), extents)  # reprolint: allow[lease-raw] fixture: same-line suppression

@@ -1,6 +1,6 @@
 """Per-assigned-architecture smoke tests: a REDUCED config of the same
 family runs one forward + one train step on CPU, asserting output shapes
-and finiteness (full configs are exercised via the dry-run only)."""
+and finiteness."""
 import jax
 import jax.numpy as jnp
 import pytest

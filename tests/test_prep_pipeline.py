@@ -1,7 +1,7 @@
 """PrepPipeline (streaming prep→train ingestion): determinism across
 target counts, checkpoint/resume through OffloadDB, admission-pushback
-re-routing, bounded-queue backpressure, and the streaming submit_many
-plane it rides on."""
+re-routing, bounded-queue backpressure, and the streaming
+``submit(specs, stream=True)`` plane it rides on."""
 import time
 
 import numpy as np

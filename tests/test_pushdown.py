@@ -1,5 +1,5 @@
 """Pushdown operator plane — verifier fuzz, defense in depth, E2E
-correctness, and the offload-API deprecation regression (PR 8).
+correctness, and the agreement of the offload API's submit spellings.
 
 The differential property itself (pushdown ≡ block shipping ≡ dict model
 on random corpora/programs) lives in tests/test_property.py with its
@@ -13,14 +13,12 @@ crafted scenarios those generators would only hit by luck:
     tombstone) on one target suppresses an older matching version on
     another;
   * the single-stripe aggregate fast path ships only aggregate state;
-  * the deprecated ``submit_task`` / ``submit_async`` / ``submit_many``
-    shims behave identically to unified ``submit`` and warn, while the
-    unified path never warns.
+  * every spelling of ``submit`` (one spec, a list, ``async_=True``, the
+    positional single-task form) returns the same ``(result, where)``.
 """
 import os
 import sys
 import time
-import warnings
 
 import pytest
 
@@ -289,7 +287,7 @@ def test_pushdown_flag_degrades_gracefully_without_engines():
     assert not fs2._leases
 
 
-# --------------------------------------------- deprecation regression
+# ------------------------------------------------- submit spellings
 def _stub_sum(io, block, nblocks):
     return sum(io.offload_read(block, nblocks)) % 65536
 
@@ -309,41 +307,15 @@ def _offload_plane():
     return fs, off, ino.extents, ino.mtime
 
 
-def test_deprecated_shims_warn_and_behave_identically():
+def test_submit_spellings_agree():
     fs, off, ext, mtime = _offload_plane()
     spec = {"task": "sum", "args": (ext[0].block, 1),
             "read_extents": ext, "mtime": mtime}
-    new = off.submit(dict(spec))
-    assert new == (7 * BLOCK_SIZE % 65536, "storage0")
-    with pytest.warns(DeprecationWarning, match="submit_task is deprecated"):
-        # reprolint: allow[deprecated-api] back-compat coverage for the deprecated shim itself
-        old = off.submit_task("sum", ext[0].block, 1,
-                              read_extents=ext, mtime=mtime)
-    assert old == new
-    with pytest.warns(DeprecationWarning, match="submit_async is deprecated"):
-        # reprolint: allow[deprecated-api] back-compat coverage for the deprecated shim itself
-        fut = off.submit_async("sum", ext[0].block, 1,
-                               read_extents=ext, mtime=mtime)
-    assert fut.result(timeout=30) == new
-    with pytest.warns(DeprecationWarning, match="submit_many is deprecated"):
-        # reprolint: allow[deprecated-api] back-compat coverage for the deprecated shim itself
-        many = off.submit_many([dict(spec), dict(spec)])
-    assert many == [new, new]
-    wait_no_leases(fs)
-
-
-def test_unified_submit_paths_never_warn():
-    fs, off, ext, mtime = _offload_plane()
-    spec = {"task": "sum", "args": (ext[0].block, 1),
-            "read_extents": ext, "mtime": mtime}
-    with warnings.catch_warnings():
-        warnings.simplefilter("error", DeprecationWarning)
-        r1 = off.submit(dict(spec))
-        r2 = off.submit([dict(spec)])[0]
-        r3 = off.submit(dict(spec), async_=True).result(timeout=30)
-        # the legacy positional form delegates without warning by design:
-        # it IS the submit entry point, just the pre-spec spelling
-        r4 = off.submit("sum", ext[0].block, 1,
-                        read_extents=ext, mtime=mtime)
+    r1 = off.submit(dict(spec))
+    r2 = off.submit([dict(spec)])[0]
+    r3 = off.submit(dict(spec), async_=True).result(timeout=30)
+    # the positional form is the pre-spec spelling of the same entry point
+    r4 = off.submit("sum", ext[0].block, 1, read_extents=ext, mtime=mtime)
+    assert r1 == (7 * BLOCK_SIZE % 65536, "storage0")
     assert r1 == r2 == r3 == r4
     wait_no_leases(fs)

@@ -218,7 +218,7 @@ def test_db_crash_remount_recovers_durable_prefix_and_reclaims_orphans():
         expected[k] = v
     db.wal.wait_durable()
     fs.flush_metadata()
-    # crash with an un-released submit_many-style write lease outstanding
+    # crash with an un-released batched job's write lease outstanding
     fs.create("/pending-output")
     fs.fallocate("/pending-output", 32 * 1024)
     # reprolint: allow[lease-raw] deliberate orphan grants: journal replay + fencing under test

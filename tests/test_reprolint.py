@@ -30,10 +30,9 @@ def analyze(*names, rules=None, baseline=None):
 
 
 # ------------------------------------------------------------------ registry
-def test_registry_has_exactly_the_five_passes():
+def test_registry_has_exactly_the_four_passes():
     assert set(PASSES) == {"lease-raw", "blocking-under-lock",
-                           "journal-before-mutate", "layering",
-                           "deprecated-api"}
+                           "journal-before-mutate", "layering"}
     for rule, mod in PASSES.items():
         assert mod.RULE == rule
         assert mod.DOC
@@ -114,26 +113,12 @@ def test_layering_module_identity_uses_last_src_segment():
     assert module_name("benchmarks/fig15_async_wal.py") is None
 
 
-def test_deprecated_api_flagged():
-    res = analyze("deprecated_bad.py")
-    assert [f.rule for f in res.findings] == ["deprecated-api"] * 3
-    joined = " ".join(f.message for f in res.findings)
-    for shim in ("submit_task", "submit_many", "submit_async"):
-        assert shim in joined
-
-
-def test_deprecated_api_clean_shapes():
-    res = analyze("deprecated_ok.py")
-    assert res.findings == []
-
-
 # ------------------------------------------------------------- suppressions
 def test_suppression_with_reason_suppresses_both_placements():
     res = analyze("suppressed.py")
     assert res.findings == []
     assert len(res.suppressed) == 2
-    assert {f.rule for f in res.suppressed} == {"lease-raw",
-                                               "deprecated-api"}
+    assert [f.rule for f in res.suppressed] == ["lease-raw"] * 2
 
 
 def test_suppression_without_reason_does_not_suppress():
@@ -143,11 +128,17 @@ def test_suppression_without_reason_does_not_suppress():
     assert "reason" in res.findings[0].message
 
 
-def test_suppression_is_rule_scoped():
-    # an allow[deprecated-api] comment must not hide a lease-raw finding
-    res = analyze("suppressed.py", rules=["lease-raw"])
-    assert res.findings == []
-    assert len(res.suppressed) == 1
+def test_suppression_is_rule_scoped(tmp_path):
+    # an allow[layering] comment must not hide a lease-raw finding
+    src = tmp_path / "scoped.py"
+    src.write_text(
+        "def grab(fs, extents):\n"
+        "    return fs.grant_lease((), extents)  "
+        "# reprolint: allow[layering] names another rule\n",
+        encoding="utf-8")
+    res = run([src], exclude=NO_FIXTURE_EXCLUDE)
+    assert [f.rule for f in res.findings] == ["lease-raw"]
+    assert res.suppressed == []
 
 
 # ----------------------------------------------------------------- baseline
@@ -166,7 +157,7 @@ def test_baseline_survives_line_drift(tmp_path):
     a = Finding("p.py", 10, "lease-raw", "m", "lease = fs.grant_lease(x)")
     b = Finding("p.py", 99, "lease-raw", "m", "lease = fs.grant_lease(x)")
     assert a.fingerprint == b.fingerprint
-    c = Finding("p.py", 10, "deprecated-api", "m",
+    c = Finding("p.py", 10, "layering", "m",
                 "lease = fs.grant_lease(x)")
     assert a.fingerprint != c.fingerprint  # rule is part of the hash
 
@@ -224,12 +215,12 @@ def test_cli_write_baseline_then_clean(tmp_path, capsys):
 def test_cli_json_format(tmp_path, capsys):
     bl = tmp_path / "bl.txt"
     bl.write_text("", encoding="utf-8")
-    rc = cli_main([str(FIX / "deprecated_bad.py"), "--no-default-excludes",
+    rc = cli_main([str(FIX / "leases_bad.py"), "--no-default-excludes",
                    "--baseline", str(bl), "--format", "json"])
     assert rc == 1
     payload = json.loads(capsys.readouterr().out)
-    assert len(payload["findings"]) == 3
-    assert all(f["rule"] == "deprecated-api" for f in payload["findings"])
+    assert len(payload["findings"]) == 2
+    assert all(f["rule"] == "lease-raw" for f in payload["findings"])
     assert all(f["fingerprint"] for f in payload["findings"])
 
 

@@ -3,7 +3,7 @@ catalog translated to OffloadFS, driven through ``FaultyFabric`` under
 fixed seeds:
 
   * membership: join / leave / drain, endpoint-less targets skipped
-  * target death mid-``submit_many``: no lost task, no leaked lease
+  * target death mid-batch: no lost task, no leaked lease
   * health: probe-failure quarantine, stale-telemetry quarantine (aging),
     rejoin on recovery, health-channel-only partitions
   * priority: background queued behind foreground under overload, shedding
@@ -65,8 +65,8 @@ def stub_fill(io, block, nblocks, byte):
 
 
 def wait_no_leases(fs, timeout=5.0):
-    """submit_async releases the lease right AFTER resolving its future
-    (same worker thread) — give that release the instant it needs."""
+    """A submitted spec releases its lease right AFTER resolving its
+    future (same worker thread) — give that release the instant it needs."""
     deadline = time.time() + timeout
     while fs._leases and time.time() < deadline:
         time.sleep(0.002)
@@ -152,7 +152,7 @@ def test_router_runs_local_when_no_targets_left():
     assert not fs._leases
 
 
-# ------------------------------------------------ death mid-submit_many
+# ------------------------------------------------------ death mid-batch
 def test_target_death_mid_submit_many_loses_no_task_leaks_no_lease():
     """Acceptance: kill one of four targets with its wire batch already
     committed — every share still lands (reroute or local), the device

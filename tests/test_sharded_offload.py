@@ -2,6 +2,7 @@
 threads × initiators × shards, admission rejection, backpressure, cache
 pinning bounds, and load-balance tolerance."""
 import threading
+import time
 
 import pytest
 
@@ -224,6 +225,61 @@ def test_failed_flush_round_keeps_data_and_reclaims_outputs():
     assert db.imm == [] and len(db.levels[0]) == n_imm
     for k, v in model.items():
         assert db.get(k) == v
+
+
+# ------------------------------------------- a failing target's share
+def slow_fill(io, block, nblocks, byte):
+    time.sleep(0.2)  # settles well after the other target has failed
+    io.offload_write(block, bytes([byte]) * (nblocks * BLOCK_SIZE))
+    return nblocks
+
+
+def broken_fill(io, *a, **kw):
+    raise RuntimeError("target down")
+
+
+def make_file(fs, path):
+    fs.create(path)
+    fs.write(path, bytes(BLOCK_SIZE), 0)
+    return fs.stat(path).extents
+
+
+def fill_spec(ext, target):
+    return {"task": "fill", "args": (ext[0].block, 1, 0x5A),
+            "write_extents": ext, "target": target}
+
+
+def test_batch_with_a_failing_target_raises_after_every_share_settles():
+    """``submit(specs)`` is a barrier: when one target's share fails the
+    call raises that error, but only after the healthy target's share has
+    run, and no lease or in-flight count outlives the call."""
+    _, fs, _, engines, off = build_plane(2)
+    engines[0].register_stub("fill", slow_fill)
+    engines[1].register_stub("fill", broken_fill)
+    a, b = make_file(fs, "/a"), make_file(fs, "/b")
+    with pytest.raises(RuntimeError, match="target down"):
+        off.submit([fill_spec(b, "storage1"), fill_spec(a, "storage0")])
+    assert not fs._leases
+    assert fs.lease_journal.replay() == {}
+    assert set(off._outstanding.values()) == {0}
+    assert fs.read("/a") == bytes([0x5A]) * BLOCK_SIZE  # healthy share ran
+    assert fs.read("/b") == bytes(BLOCK_SIZE)
+
+
+def test_async_spec_against_a_failing_target_resolves_with_its_error():
+    """``submit(spec, async_=True)`` returns one future; a failing target
+    resolves it with the target's exception and releases its lease."""
+    _, fs, _, engines, off = build_plane(1)
+    engines[0].register_stub("fill", broken_fill)
+    ext = make_file(fs, "/a")
+    fut = off.submit(fill_spec(ext, "storage0"), async_=True)
+    assert isinstance(fut.exception(timeout=30), RuntimeError)
+    with pytest.raises(RuntimeError, match="target down"):
+        fut.result(timeout=30)
+    assert not fs._leases
+    assert fs.lease_journal.replay() == {}
+    assert set(off._outstanding.values()) == {0}
+    assert fs.read("/a") == bytes(BLOCK_SIZE)
 
 
 # --------------------------------------------- striped placement routing

@@ -1,9 +1,9 @@
 """reprolint core — findings, suppressions, baseline, file walking.
 
 The analyzer encodes the repo's correctness conventions (lease discipline,
-no blocking under locks, journal-before-mutate, layering DAG, deprecated
-API) as AST passes over the source tree. This module is the harness: it
-walks the paths, parses each module once, dispatches the registered passes
+no blocking under locks, journal-before-mutate, layering DAG) as AST
+passes over the source tree. This module is the harness: it walks the
+paths, parses each module once, dispatches the registered passes
 (``tools.reprolint.passes``), and post-filters the findings through inline
 suppressions and the checked-in baseline.
 
